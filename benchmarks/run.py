@@ -31,7 +31,6 @@ def main() -> None:
     from repro.launch.env import apply_env, host_fingerprint
 
     apply_env()
-    host = host_fingerprint()
 
     from benchmarks import (
         decode,
@@ -60,6 +59,10 @@ def main() -> None:
         "policies": lambda: policies.run(batch=32 if args.fast else 64),
         "roofline": lambda: roofline.run("single") + roofline.run("multi"),
     }
+    host = host_fingerprint()  # after the imports above: jax names the backend
+    from repro.launch.compile_cache import use_compile_cache
+
+    use_compile_cache()
     if args.only:
         keep = set(args.only.split(","))
         benches = {k: v for k, v in benches.items() if k in keep}

@@ -33,25 +33,12 @@ import dataclasses
 
 import jax.numpy as jnp
 
-from repro.analysis.taint import ClosedJaxpr, Jaxpr, JaxprEqn, Var  # noqa: F401
+from repro.analysis.taint import ClosedJaxpr, Jaxpr, JaxprEqn, Var, call_body  # noqa: F401
 
-_CALL_PRIMS = ("pjit", "closed_call", "core_call", "xla_call")
-_REMAT_PRIMS = ("remat", "remat2", "checkpoint")
-_CUSTOM_PRIMS = (
-    "custom_jvp_call",
-    "custom_vjp_call",
-    "custom_jvp_call_jaxpr",
-    "custom_vjp_call_jaxpr",
-)
 # single-use eqns through which a captured cotangent stays determined
 _CHAIN_PRIMS = frozenset(
     {"add", "sub", "convert_element_type", "transpose", "reshape"}
 )
-
-
-def _custom_body(eqn: JaxprEqn) -> Jaxpr:
-    sub = eqn.params.get("call_jaxpr") or eqn.params.get("fun_jaxpr")
-    return sub.jaxpr if isinstance(sub, ClosedJaxpr) else sub
 
 
 def _grad_carrying(v) -> bool:
@@ -107,13 +94,7 @@ class ForwardUses:
                     else:
                         self._stop(bv)
                 self._walk(body)
-            elif prim in _CALL_PRIMS or prim in _REMAT_PRIMS or prim in _CUSTOM_PRIMS:
-                if prim in _CALL_PRIMS:
-                    body = eqn.params["jaxpr"].jaxpr
-                elif prim in _REMAT_PRIMS:
-                    body = eqn.params["jaxpr"]
-                else:
-                    body = _custom_body(eqn)
+            elif (body := call_body(eqn)) is not None:
                 for a, bv in zip(eqn.invars, body.invars):
                     self._ident(a, bv)
                 for bv, ov in zip(body.outvars, eqn.outvars):
@@ -211,14 +192,7 @@ def live_invars(
                 if not changed:
                     break
             mark_eqn_invars(eqn, in_live)
-        elif prim in _CALL_PRIMS:
-            in_live = live_invars(eqn.params["jaxpr"].jaxpr, outs_live, cuts)
-            mark_eqn_invars(eqn, in_live)
-        elif prim in _REMAT_PRIMS:
-            in_live = live_invars(eqn.params["jaxpr"], outs_live, cuts)
-            mark_eqn_invars(eqn, in_live)
-        elif prim in _CUSTOM_PRIMS:
-            body = _custom_body(eqn)
+        elif (body := call_body(eqn)) is not None:
             in_live = live_invars(body, outs_live, cuts)
             mark_eqn_invars(eqn, in_live)
         elif prim == "cond":

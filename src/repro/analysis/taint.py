@@ -31,24 +31,18 @@ recorded activations faithful needs the value-level occupancy invariant the
 lattice cannot express, so they are surfaced separately as *routed* sites
 for the per-config allowlist (``repro.analysis.allowlist``).
 
-This module walks jax internals (``jax._src.core``); the repo pins
-jax 0.4.37 (see .github/workflows/tier1.yml) and the import guard below
-keeps the public-API fallback alive for nearby versions.
+This module walks jax internals (``jax._src.core``).  Call-like primitives
+(``jit``, remat, custom-derivative calls) are recognised by structure — see
+:func:`call_body` — so a renamed primitive does not silently fall through to
+the conservative fallback.
 """
 from __future__ import annotations
 
 import dataclasses
 from typing import Any, Optional
 
-try:  # jax 0.4.x: the public aliases re-export these; _src is the stable home
-    from jax._src.core import ClosedJaxpr, Jaxpr, JaxprEqn, Literal, Var
-except ImportError:  # pragma: no cover - newer/older layouts
-    from jax.core import ClosedJaxpr, Jaxpr, JaxprEqn, Literal, Var  # type: ignore
-
-try:
-    from jax._src import source_info_util as _siu
-except ImportError:  # pragma: no cover
-    _siu = None
+from jax._src import source_info_util as _siu
+from jax._src.core import ClosedJaxpr, Jaxpr, JaxprEqn, Literal, Var
 
 TRAIL_CAP = 8
 _SCAN_FIXPOINT_CAP = 16
@@ -74,13 +68,34 @@ def eqn_summary(eqn: JaxprEqn) -> str:
     outs = ",".join(
         "x".join(map(str, getattr(v.aval, "shape", ()))) for v in eqn.outvars
     )
-    src = ""
-    if _siu is not None:
-        try:
-            src = f" @ {_siu.summarize(eqn.source_info)}"
-        except Exception:  # pragma: no cover - source info shape changed
-            src = ""
-    return f"{eqn.primitive.name}[{ins}->{outs}]{src}"
+    return f"{eqn.primitive.name}[{ins}->{outs}] @ {_siu.summarize(eqn.source_info)}"
+
+
+# control-flow primitives with their own transfer rules (their sub-jaxprs
+# do not map one-to-one onto the eqn's operands)
+CONTROL_PRIMS = frozenset({"scan", "while", "cond"})
+
+
+def call_body(eqn: JaxprEqn) -> Optional[Jaxpr]:
+    """The body of a call-like eqn, or None.
+
+    A call is an eqn carrying one sub-jaxpr whose invars and outvars map
+    one-to-one onto the eqn's own (``jit``, remat/checkpoint,
+    ``custom_jvp_call``/``custom_vjp_call``, ...).  Recognising calls by
+    this structure rather than by primitive name keeps the passes exact
+    across jax releases that rename them (``pjit`` became ``jit``).
+    """
+    if eqn.primitive.name in CONTROL_PRIMS:
+        return None
+    for sub in eqn.params.values():
+        body = sub.jaxpr if isinstance(sub, ClosedJaxpr) else sub
+        if (
+            isinstance(body, Jaxpr)
+            and len(body.invars) == len(eqn.invars)
+            and len(body.outvars) == len(eqn.outvars)
+        ):
+            return body
+    return None
 
 
 @dataclasses.dataclass
@@ -291,9 +306,7 @@ class TaintInterpreter:
         if all(t is None for t in in_t):
             # clean in, clean out — except subjaxpr prims, which may need
             # tapness threaded (a tap slice rides scan xs while clean)
-            if prim not in ("scan", "pjit", "remat", "checkpoint", "cond",
-                            "while", "custom_jvp_call", "custom_vjp_call",
-                            "custom_vjp_call_jaxpr") or all(
+            if (prim not in CONTROL_PRIMS and call_body(eqn) is None) or all(
                 n is None for n in in_tap
             ):
                 return None
@@ -468,28 +481,8 @@ class TaintInterpreter:
         if prim == "cond":
             return self._cond(eqn, in_t, in_tap)
 
-        if prim in ("pjit", "closed_call", "core_call", "xla_call"):
-            closed = eqn.params["jaxpr"]
-            outs, out_taps = self._run_jaxpr(
-                closed.jaxpr, in_t, in_tap
-            )
-            for v, name in zip(eqn.outvars, out_taps):
-                if name is not None:
-                    taps[v] = name
-            return outs
-
-        if prim in ("remat", "checkpoint", "remat2"):
-            body = eqn.params["jaxpr"]  # open Jaxpr
-            outs, out_taps = self._run_jaxpr(body, in_t, in_tap)
-            for v, name in zip(eqn.outvars, out_taps):
-                if name is not None:
-                    taps[v] = name
-            return outs
-
-        if prim in ("custom_jvp_call", "custom_vjp_call", "custom_vjp_call_jaxpr",
-                    "custom_jvp_call_jaxpr"):
-            sub = eqn.params.get("call_jaxpr") or eqn.params.get("fun_jaxpr")
-            body = sub.jaxpr if isinstance(sub, ClosedJaxpr) else sub
+        body = call_body(eqn)
+        if body is not None:
             outs, out_taps = self._run_jaxpr(body, in_t, in_tap)
             for v, name in zip(eqn.outvars, out_taps):
                 if name is not None:

@@ -33,10 +33,15 @@ ratifying a fleet agreement is not enough, unlike branch overrides).
 Both impls of every op compute the same sums over the same tiles; only
 scheduling and HBM traffic differ, so a flipped choice moves cost, never
 results (tested).
+
+Under a mesh (``repro.parallel.reshard.use_reshard_rules``) the clipping
+kernels run per device on that device's samples
+(``reshard.split_over_samples``): GSPMD cannot partition a Mosaic kernel.
 """
 from __future__ import annotations
 
 import contextlib
+import functools
 from typing import Iterator, Mapping, Optional
 
 import jax
@@ -45,6 +50,7 @@ import jax.numpy as jnp
 from repro.kernels.flash_attention import ops as fops
 from repro.kernels.ghost_norm import ops as gops
 from repro.kernels.psg_contract import ops as cops
+from repro.parallel.reshard import split_over_samples
 
 OPS = ("ghost_norm", "embedding_ghost_norm", "psg_contract", "flash_attention")
 IMPLS = ("pallas", "xla")
@@ -138,7 +144,8 @@ def ghost_norm_sq(
     if resolve("ghost_norm", impl) == "pallas":
         from repro.kernels.ghost_norm.ghost_norm import ghost_norm_sq_pallas
 
-        return ghost_norm_sq_pallas(a, g, interpret=_interpret())
+        kernel = functools.partial(ghost_norm_sq_pallas, interpret=_interpret())
+        return split_over_samples(kernel, a, g)
     return gops.ghost_norm_sq(a, g, block=block)
 
 
@@ -155,7 +162,10 @@ def embedding_ghost_norm_sq(
             embedding_ghost_norm_sq_pallas,
         )
 
-        return embedding_ghost_norm_sq_pallas(ids, g, interpret=_interpret())
+        kernel = functools.partial(
+            embedding_ghost_norm_sq_pallas, interpret=_interpret()
+        )
+        return split_over_samples(kernel, ids, g)
     return gops.embedding_ghost_norm_sq(ids, g, block=block)
 
 
@@ -176,7 +186,12 @@ def book_weighted_grad(
             book_weighted_grad_pallas,
         )
 
-        return book_weighted_grad_pallas(a, g, w, interpret=_interpret())
+        # rows are (sample, position) pairs: the sum over them splits by
+        # sample across devices
+        kernel = functools.partial(
+            book_weighted_grad_pallas, interpret=_interpret()
+        )
+        return split_over_samples(kernel, a, g, w, dim=1, reduce=True)
     return cops.book_weighted_grad(a, g, w)
 
 
@@ -199,9 +214,8 @@ def psg_contract(
         moved = jnp.moveaxis(psg, axis, 0)
         out_shape = moved.shape[1:]
         flat = moved.reshape(moved.shape[0], -1)
-        return psg_contract_pallas(flat, c, interpret=_interpret()).reshape(
-            out_shape
-        )
+        kernel = functools.partial(psg_contract_pallas, interpret=_interpret())
+        return split_over_samples(kernel, flat, c, reduce=True).reshape(out_shape)
     return jnp.tensordot(
         c.astype(jnp.float32), psg.astype(jnp.float32), axes=(0, axis)
     )

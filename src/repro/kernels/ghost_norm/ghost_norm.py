@@ -34,6 +34,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+_LANES = 128  # per-sample sums leave the kernel as one lane-dense row each
+
 
 def _pad(x, axis, mult):
     p = (-x.shape[axis]) % mult
@@ -106,11 +108,11 @@ def ghost_norm_sq_pallas(
 
             @pl.when(jnp.logical_and(i == 0, j == 0))
             def _first():
-                o_ref[0] = contrib
+                o_ref[...] = jnp.full(o_ref.shape, contrib, jnp.float32)
 
             @pl.when(jnp.logical_or(i != 0, j != 0))
             def _rest():
-                o_ref[0] += contrib
+                o_ref[...] += contrib
 
     return pl.pallas_call(
         kernel,
@@ -121,14 +123,14 @@ def ghost_norm_sq_pallas(
             pl.BlockSpec((1, block_t, block_f), grow_i),
             pl.BlockSpec((1, block_t, block_f), grow_j),
         ],
-        out_specs=pl.BlockSpec((1,), lambda ni, i, j, c: (ni,)),
-        out_shape=jax.ShapeDtypeStruct((n,), jnp.float32),
+        out_specs=pl.BlockSpec((1, 1, _LANES), lambda ni, i, j, c: (ni, 0, 0)),
+        out_shape=jax.ShapeDtypeStruct((n, 1, _LANES), jnp.float32),
         scratch_shapes=[
             pltpu.VMEM((block_t, block_t), jnp.float32),
             pltpu.VMEM((block_t, block_t), jnp.float32),
         ],
         interpret=interpret,
-    )(a, a, g, g)
+    )(a, a, g, g)[:, 0, 0]
 
 
 @functools.partial(jax.jit, static_argnames=("block_t", "block_f", "interpret"))
@@ -177,34 +179,31 @@ def embedding_ghost_norm_sq_pallas(
 
         @pl.when(jnp.logical_and(c == nc - 1, live))
         def _finalize():
-            eq = (
-                idi_ref[...].reshape(block_t, 1)
-                == idj_ref[...].reshape(1, block_t)
-            ).astype(jnp.float32)
+            eq = (idi_ref[0, 0][:, None] == idj_ref[0]).astype(jnp.float32)
             weight = jnp.where(i == j, 1.0, 2.0).astype(jnp.float32)
             contrib = weight * jnp.sum(eq * gg_acc[...])
 
             @pl.when(jnp.logical_and(i == 0, j == 0))
             def _first():
-                o_ref[0] = contrib
+                o_ref[...] = jnp.full(o_ref.shape, contrib, jnp.float32)
 
             @pl.when(jnp.logical_or(i != 0, j != 0))
             def _rest():
-                o_ref[0] += contrib
+                o_ref[...] += contrib
 
     return pl.pallas_call(
         kernel,
         grid=(n, nb, nb, nc),
         in_specs=[
-            pl.BlockSpec((1, block_t), lambda ni, i, j, c: (ni, i)),
-            pl.BlockSpec((1, block_t), lambda ni, i, j, c: (ni, j)),
+            pl.BlockSpec((1, 1, block_t), lambda ni, i, j, c: (ni, 0, i)),
+            pl.BlockSpec((1, 1, block_t), lambda ni, i, j, c: (ni, 0, j)),
             pl.BlockSpec((1, block_t, block_f), lambda ni, i, j, c: (ni, i, c)),
             pl.BlockSpec((1, block_t, block_f), lambda ni, i, j, c: (ni, j, c)),
         ],
-        out_specs=pl.BlockSpec((1,), lambda ni, i, j, c: (ni,)),
-        out_shape=jax.ShapeDtypeStruct((n,), jnp.float32),
+        out_specs=pl.BlockSpec((1, 1, _LANES), lambda ni, i, j, c: (ni, 0, 0)),
+        out_shape=jax.ShapeDtypeStruct((n, 1, _LANES), jnp.float32),
         scratch_shapes=[
             pltpu.VMEM((block_t, block_t), jnp.float32),
         ],
         interpret=interpret,
-    )(ids_i, ids_j, g, g)
+    )(ids_i[:, None, :], ids_j[:, None, :], g, g)[:, 0, 0]

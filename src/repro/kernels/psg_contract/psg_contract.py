@@ -72,7 +72,7 @@ def book_weighted_grad_pallas(
 
     def kernel(a_ref, g_ref, w_ref, o_ref):
         ri = pl.program_id(3)
-        gw = g_ref[0].astype(jnp.float32) * w_ref[0][:, None]
+        gw = g_ref[0].astype(jnp.float32) * w_ref[0, 0][:, None]
         contrib = jax.lax.dot_general(
             a_ref[0].astype(jnp.float32), gw,
             (((0,), (0,)), ((), ())),
@@ -93,7 +93,7 @@ def book_weighted_grad_pallas(
         in_specs=[
             pl.BlockSpec((1, block_r, block_d), lambda mi, i, j, ri: (mi, ri, i)),
             pl.BlockSpec((1, block_r, block_p), lambda mi, i, j, ri: (mi, ri, j)),
-            pl.BlockSpec((1, block_r), lambda mi, i, j, ri: (mi, ri)),
+            pl.BlockSpec((1, 1, block_r), lambda mi, i, j, ri: (mi, 0, ri)),
         ],
         out_specs=pl.BlockSpec(
             (1, block_d, block_p), lambda mi, i, j, ri: (mi, i, j)
@@ -102,7 +102,7 @@ def book_weighted_grad_pallas(
             (m, nd * block_d, np_ * block_p), jnp.float32
         ),
         interpret=interpret,
-    )(a, g, w)
+    )(a, g, w[:, None, :])
     return out[:, :d, :p]
 
 
@@ -132,7 +132,7 @@ def psg_contract_pallas(
             c_ref[...], p_ref[...].astype(jnp.float32),
             (((1,), (0,)), ((), ())),
             preferred_element_type=jnp.float32,
-        )[0]
+        )
 
         @pl.when(ni == 0)
         def _first():
@@ -149,8 +149,8 @@ def psg_contract_pallas(
             pl.BlockSpec((block_n, block_f), lambda i, ni: (ni, i)),
             pl.BlockSpec((1, block_n), lambda i, ni: (0, ni)),
         ],
-        out_specs=pl.BlockSpec((block_f,), lambda i, ni: (i,)),
-        out_shape=jax.ShapeDtypeStruct((nf * block_f,), jnp.float32),
+        out_specs=pl.BlockSpec((1, block_f), lambda i, ni: (0, i)),
+        out_shape=jax.ShapeDtypeStruct((1, nf * block_f), jnp.float32),
         interpret=interpret,
     )(psg, c2)
-    return out[:f]
+    return out[0, :f]

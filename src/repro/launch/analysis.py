@@ -6,18 +6,40 @@ Sources (per §Roofline):
   from the operand/output sizes of every all-gather / all-reduce /
   reduce-scatter / all-to-all / collective-permute
 
-Hardware model (TPU v5e): 197 TFLOP/s bf16 per chip, 819 GB/s HBM,
-~50 GB/s/link ICI.  Effective wire bytes per collective use the standard
-ring-algorithm factors with the participant count parsed from replica_groups.
+Hardware model: the published per-chip peaks in ``PEAKS``, keyed by the
+``device_kind`` jax reports.  Effective wire bytes per collective use the
+standard ring-algorithm factors with the participant count parsed from
+replica_groups.
 """
 from __future__ import annotations
 
 import dataclasses
 import re
 
-PEAK_FLOPS = 197e12  # bf16 / chip
-HBM_BW = 819e9  # bytes/s / chip
-ICI_BW = 50e9  # bytes/s / link (per direction)
+
+@dataclasses.dataclass(frozen=True)
+class Peaks:
+    flops: float  # bf16 FLOP/s per chip
+    hbm_bw: float  # HBM bytes/s per chip
+    ici_bw: float  # ICI bytes/s per link
+
+
+# Google Cloud documentation, "TPU v5e": 197 TFLOP/s bf16, 819 GB/s HBM,
+# 1,600 Gbit/s of chip-to-chip interconnect (4 links, so 50 GB/s each)
+PEAKS = {
+    "TPU v5 lite": Peaks(flops=197e12, hbm_bw=819e9, ici_bw=50e9),
+}
+
+
+def peaks_for(device_kind: str) -> Peaks:
+    """The published peaks of ``device_kind``; an unknown kind is an error."""
+    try:
+        return PEAKS[device_kind]
+    except KeyError:
+        raise ValueError(
+            f"no published peaks for device kind {device_kind!r}; "
+            f"known kinds: {sorted(PEAKS)}"
+        ) from None
 
 _DTYPE_BYTES = {
     "pred": 1, "s8": 1, "u8": 1, "s16": 2, "u16": 2, "bf16": 2, "f16": 2,
@@ -118,6 +140,7 @@ class RooflineTerms:
 def roofline_terms(
     compiled,
     *,
+    device_kind: str,
     n_devices: int,
     flops_global: float,
     bytes_per_device: float,
@@ -137,9 +160,10 @@ def roofline_terms(
             + ma.temp_size_in_bytes - ma.alias_size_in_bytes
         ),
     }
-    compute_s = flops / PEAK_FLOPS
-    memory_s = byts / HBM_BW
-    collective_s = wire_bytes_per_device / ICI_BW
+    peaks = peaks_for(device_kind)
+    compute_s = flops / peaks.flops
+    memory_s = byts / peaks.hbm_bw
+    collective_s = wire_bytes_per_device / peaks.ici_bw
     terms = {"compute": compute_s, "memory": memory_s, "collective": collective_s}
     bottleneck = max(terms, key=terms.get)
     total_hlo = flops * n_devices

@@ -66,6 +66,10 @@ from repro.utils.logging import get_logger
 
 log = get_logger("dryrun")
 
+# the production meshes model v5e pods; the lowering itself runs on
+# placeholder host devices, so the roofline names the kind it models
+TARGET_DEVICE_KIND = "TPU v5 lite"
+
 
 def _lower(cfg: ArchConfig, shape: ShapeConfig, mode: str, mesh):
     """Build the step for one cell and AOT-compile it.
@@ -200,6 +204,7 @@ def lower_cell(arch_name: str, shape_name: str, *, multi_pod: bool,
 
     terms = analysis.roofline_terms(
         compiled,
+        device_kind=TARGET_DEVICE_KIND,
         n_devices=n_devices,
         flops_global=flops["total"],
         bytes_per_device=bytes_corr,

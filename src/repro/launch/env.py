@@ -80,16 +80,19 @@ def apply_env(host_devices: int | None = None) -> None:
     for key, value in ENV_DEFAULTS.items():
         os.environ.setdefault(key, value)
     flags = dict(XLA_FLAG_DEFAULTS)
-    if _backend() == "tpu":
+    # the TPU flags need a pinned platform: with JAX_PLATFORMS unset the
+    # backend is unknown until jax initializes, and a CPU backend aborts
+    # on them
+    if _pinned_platform() == "tpu":
         flags.update(TPU_XLA_FLAG_DEFAULTS)
     if host_devices is not None:
         flags["--xla_force_host_platform_device_count"] = str(host_devices)
     merge_xla_flags(flags)
 
 
-def _backend() -> str:
-    """The backend this process will target, without importing jax."""
-    return os.environ.get("JAX_PLATFORMS", "cpu").split(",")[0] or "cpu"
+def _pinned_platform() -> str:
+    """The platform ``JAX_PLATFORMS`` pins, or ``unknown`` when unset."""
+    return os.environ.get("JAX_PLATFORMS", "").split(",")[0] or "unknown"
 
 
 def host_fingerprint() -> str:
@@ -97,8 +100,12 @@ def host_fingerprint() -> str:
 
     ``machine-cpucount-backend`` (e.g. ``x86_64-8-cpu``): two rows with
     equal fingerprints were produced on comparable hosts, so the step-time
-    gate may compare them; rows from different classes never pair.  The
-    backend component comes from ``JAX_PLATFORMS`` when set (cheap, no jax
-    import) and defaults to ``cpu`` — matching the tier-1 harness.
+    gate may compare them; rows from different classes never pair.  Once
+    jax is imported the backend is jax's own answer; before that it is the
+    ``JAX_PLATFORMS`` pin, or ``unknown``.
     """
-    return f"{platform.machine()}-{os.cpu_count()}-{_backend()}"
+    backend = (
+        sys.modules["jax"].default_backend() if "jax" in sys.modules
+        else _pinned_platform()
+    )
+    return f"{platform.machine()}-{os.cpu_count()}-{backend}"

@@ -46,6 +46,7 @@ from repro.data.pipeline import DataPipeline
 from repro.data.poisson import poisson_sample_mask
 from repro.data.synthetic import synthetic_arch_batch
 from repro.checkpoint.manager import CheckpointManager
+from repro.launch.compile_cache import use_compile_cache
 from repro.launch.mesh import make_host_mesh
 from repro.launch.steps import (
     DPTrainConfig,
@@ -588,12 +589,12 @@ def run_once(args, injection: Optional[InjectionPlan] = None) -> int:
                         state["params"], state["policy"], acc, batch, idx_dev[i]
                     )
                 state, metrics = fin_fn(state, acc)
-                # the ONE host sync per logical batch: bounds the dispatch
-                # queue and makes the watchdog time executed work.  The step
-                # metrics ride the SAME sync, so the record below reads
-                # already-materialized buffers — instrumentation adds no
-                # second block_until_ready (test-asserted)
-                jax.block_until_ready((state["step"], metrics))
+            # the ONE host sync per logical batch: bounds the dispatch
+            # queue and makes the watchdog (and step_s) time executed work,
+            # not the enqueue.  The step metrics ride the SAME sync, so the
+            # record below reads already-materialized buffers —
+            # instrumentation adds no second block_until_ready (test-asserted)
+            jax.block_until_ready((state["step"], metrics))
             engine.record_step()
             engine.check_epsilon_alarm(args.epsilon_alarm_frac, step=step_idx + 1)
             dt = watchdog.end_step(step_idx)
@@ -688,6 +689,7 @@ def is_retryable_failure(exc: BaseException) -> bool:
 def main(argv=None) -> int:
     args = parse_args(argv)
     reconfigure()  # re-apply $REPRO_LOG_LEVEL to module-level loggers
+    use_compile_cache()
     # ONE injection plan for the whole supervision loop: injectors are
     # one-shot, so a crash that already fired does not re-fire after the
     # in-process restart (no args surgery needed)

@@ -6,7 +6,7 @@ step M's work is synced, so the captured window contains exactly M-N+1
 logical batches of device execution.  On TPU the
 ``--xla_step_marker_location=1`` groundwork (``launch/env.py``) makes XLA
 mark each outer-loop step inside that window; on CPU/GPU the
-``TfrtCpuExecutable::Execute`` / module events carry the same information
+``PjRtCpuExecutable::Execute`` / module events carry the same information
 (``repro.obs.timeline`` extracts either).
 
 The window degrades gracefully: a backend whose profiler cannot start

@@ -15,11 +15,15 @@ medians").
 What counts as a step span is backend-dependent, so the matcher is a
 regex over event names with a default covering the backends we run:
 
-* TPU: XLA step markers (``--xla_step_marker_location=1`` via
-  ``launch/env.py``) surface as ``StepMarker``/``XlaModule`` events;
-* CPU: each compiled program execution is one ``TfrtCpuExecutable::Execute``
+* CPU: each compiled program execution is one ``PjRtCpuExecutable::Execute``
   event (an accumulation run has ``accum+1`` executions per logical step);
-* GPU: module execution lands as ``XlaModule:``-prefixed events.
+* GPU: module execution lands as ``XlaModule:``-prefixed events;
+* TPU: XLA step markers (``--xla_step_marker_location=1`` via
+  ``launch/env.py``) are expected as ``StepMarker`` events.  Without them a
+  TPU v5e trace (jax 0.9) names no execution the pattern matches: each one
+  is an event named after its module, ``jit_<fn>(<fingerprint>)``, on the
+  ``/device:TPU:0`` plane, plus a host launch event
+  ``TpuLoadedExecutable::ExecuteLaunch`` that ends before the device does.
 """
 from __future__ import annotations
 
@@ -29,9 +33,7 @@ import pathlib
 import re
 from typing import Iterable, Optional
 
-DEFAULT_STEP_PATTERN = (
-    r"StepMarker|XlaModule|TfrtCpuExecutable::Execute|TpuExecute"
-)
+DEFAULT_STEP_PATTERN = r"StepMarker|XlaModule|PjRtCpuExecutable::Execute"
 
 
 def trace_files(trace_dir) -> list[pathlib.Path]:

@@ -16,12 +16,16 @@ bf16, not fp32.
 
 Activated via ``use_reshard_rules(mesh, cfg)`` around tracing/lowering; a
 no-op otherwise (single-host smoke tests never notice).
+
+The same context tells ``split_over_samples`` how to run a Pallas kernel
+under the mesh: GSPMD cannot partition a Mosaic kernel, so each device runs
+it on its own samples inside a ``shard_map``.
 """
 from __future__ import annotations
 
 import contextlib
 import contextvars
-from typing import Optional
+from typing import Callable, Optional
 
 import jax
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
@@ -37,7 +41,8 @@ _STATE: contextvars.ContextVar[Optional[tuple]] = contextvars.ContextVar(
 def use_reshard_rules(mesh: Mesh, cfg=None):
     rules = logical_rules(mesh, cfg)
     fsdp = set(mesh_axes(mesh)["fsdp"])
-    token = _STATE.set((mesh, rules, fsdp))
+    batch = mesh_axes(mesh, cfg)["batch"]
+    token = _STATE.set((mesh, rules, fsdp, batch))
     try:
         yield
     finally:
@@ -49,7 +54,7 @@ def reshard_param(w: jax.Array, axes: tuple) -> jax.Array:
     state = _STATE.get()
     if state is None:
         return w
-    mesh, rules, fsdp = state
+    mesh, rules, fsdp, _ = state
     entries = []
     for dim, logical in zip(w.shape, axes):
         target = tuple(a for a in rules.get(logical, ()) if a not in fsdp)
@@ -75,7 +80,7 @@ def shard_seq(x: jax.Array) -> jax.Array:
     state = _STATE.get()
     if state is None or x.ndim != 3:
         return x
-    mesh, rules, fsdp = state
+    mesh, rules, fsdp, _ = state
     model = tuple(a for a in rules.get("mlp", ()) if a == "model")
     if not model or x.shape[1] % axis_size(mesh, model) != 0:
         return x
@@ -98,7 +103,7 @@ def shard_heads(x: jax.Array, axis: int = 2) -> jax.Array:
     state = _STATE.get()
     if state is None or x.ndim <= axis:
         return x
-    mesh, rules, fsdp = state
+    mesh, rules, fsdp, _ = state
     model = tuple(a for a in rules.get("heads", ()) if a == "model")
     if not model or x.shape[axis] % axis_size(mesh, model) != 0:
         return x
@@ -108,3 +113,36 @@ def shard_heads(x: jax.Array, axis: int = 2) -> jax.Array:
     if batch_ax and x.shape[0] % axis_size(mesh, batch_ax) == 0:
         spec[0] = batch_ax if len(batch_ax) > 1 else batch_ax[0]
     return jax.lax.with_sharding_constraint(x, NamedSharding(mesh, P(*spec)))
+
+
+def split_over_samples(
+    kernel: Callable, *args: jax.Array, dim: int = 0, reduce: bool = False
+) -> jax.Array:
+    """Run a Pallas kernel on each device's share of the samples.
+
+    ``dim`` of every operand holds the samples (or rows ordered by sample).
+    Under the active rules the kernel runs in a ``shard_map`` that splits
+    that dim over the batch axes (the longest prefix that divides it; none
+    means every device computes the whole): a per-sample result comes back
+    split the same way, and a sum over samples (``reduce=True``) adds the
+    devices' partial sums with one ``psum``.  Outside the rules, or on one
+    device, this is ``kernel(*args)``.
+    """
+    state = _STATE.get()
+    if state is None or state[0].size == 1:
+        return kernel(*args)
+    mesh, _, _, axes = state
+    n = args[0].shape[dim]
+    while axes and n % axis_size(mesh, axes) != 0:
+        axes = axes[:-1]
+    ax = (axes if len(axes) > 1 else axes[0]) if axes else None
+    split = P(*([None] * dim), ax)
+    if reduce:
+        fn = (lambda *a: jax.lax.psum(kernel(*a), axes)) if axes else kernel
+        out_spec = P()
+    else:
+        fn, out_spec = kernel, split
+    return jax.shard_map(
+        fn, mesh=mesh, in_specs=(split,) * len(args), out_specs=out_spec,
+        check_vma=False,
+    )(*args)

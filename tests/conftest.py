@@ -8,6 +8,9 @@ import jax  # noqa: E402
 import pytest  # noqa: E402
 
 jax.config.update("jax_enable_x64", False)
+# tests compile from scratch: no persistent cache carried between runs (the
+# CLIs under test point it at the checkout otherwise)
+jax.config.update("jax_enable_compilation_cache", False)
 
 
 @pytest.fixture(autouse=True)

@@ -159,9 +159,7 @@ def test_hygiene_clean_jaxpr():
 
 
 def test_planted_f64_promotion_detected():
-    from jax.experimental import enable_x64
-
-    with enable_x64():
+    with jax.enable_x64(True):
         closed = jax.make_jaxpr(lambda x: jnp.sin(x.astype(jnp.float64)))(
             jnp.ones(3, jnp.float32)
         )

@@ -299,16 +299,16 @@ def test_timeline_groups_synthetic_trace(tmp_path):
     trace = {
         "traceEvents": [
             # step 0: two back-to-back executions (an accum microstep pair)
-            {"ph": "X", "name": "TfrtCpuExecutable::Execute", "ts": 0,
+            {"ph": "X", "name": "PjRtCpuExecutable::Execute", "ts": 0,
              "dur": 100},
-            {"ph": "X", "name": "TfrtCpuExecutable::Execute", "ts": 110,
+            {"ph": "X", "name": "PjRtCpuExecutable::Execute", "ts": 110,
              "dur": 100},
             # 5ms of host work, then step 1
-            {"ph": "X", "name": "TfrtCpuExecutable::Execute", "ts": 5210,
+            {"ph": "X", "name": "PjRtCpuExecutable::Execute", "ts": 5210,
              "dur": 300},
             # noise: a non-matching and a non-complete event
             {"ph": "X", "name": "HostLoopOverhead", "ts": 50, "dur": 10},
-            {"ph": "B", "name": "TfrtCpuExecutable::Execute", "ts": 60},
+            {"ph": "B", "name": "PjRtCpuExecutable::Execute", "ts": 60},
         ]
     }
     d = tmp_path / "plugins" / "profile" / "2026"

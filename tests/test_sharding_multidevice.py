@@ -1,11 +1,14 @@
 """Distribution tests on 8 fake CPU devices (subprocess: device count is
 locked at first jax init, so the main test process can't host these)."""
 import json
+import pathlib
 import subprocess
 import sys
 import textwrap
 
 import pytest
+
+ROOT = pathlib.Path(__file__).resolve().parents[1]
 
 SCRIPT = textwrap.dedent(
     """
@@ -75,3 +78,54 @@ def test_sharded_train_step_matches_single_device():
     res = json.loads(out.stdout.strip().splitlines()[-1])
     assert not res["nan"]
     assert abs(res["loss_sharded"] - res["loss_ref"]) < 5e-4, res
+
+
+PALLAS_SPLIT_SCRIPT = textwrap.dedent(
+    """
+    import os
+    os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=4"
+    import json
+    import jax
+
+    import chip_smoke
+    from repro.configs.registry import get_arch
+    from repro.kernels import dispatch
+
+    cfg = get_arch("xlstm-350m").reduced()
+    out = {}
+    for mode in ("mixed_ghost", "bk_mixed"):
+        # the Pallas kernels (interpreted here) under the 4-device mesh,
+        # against one device: the comparison chip_smoke.py --chips 4 makes
+        with dispatch.force_impl("pallas"):
+            res = chip_smoke.data_parallel_case(
+                cfg, mode, batch_size=4, seq=16, micro=4, devices=jax.devices()
+            )
+        (loss4, grads4, norms4, _), (loss1, grads1, norms1, _) = res["mesh"], res["one"]
+        grad, leaves = chip_smoke.grad_errors(grads4, grads1)
+        out[mode] = {
+            "loss": abs(loss4 - loss1),
+            "grad": grad,
+            "leaf": max(leaves.values()),
+            "norms": float(abs(norms4 - norms1).max()),
+            "devices": len(res["held"]),
+        }
+    print(json.dumps(out))
+    """
+)
+
+
+def test_pallas_kernels_split_over_the_mesh():
+    """The clipping kernels run per device under a mesh (GSPMD cannot
+    partition them): clipped grads on 4 devices equal one device's."""
+    out = subprocess.run(
+        [sys.executable, "-c", PALLAS_SPLIT_SCRIPT],
+        capture_output=True, text=True, timeout=600, cwd=ROOT,
+        env={"PYTHONPATH": f"{ROOT}:{ROOT}/src", "PATH": "/usr/bin:/bin",
+             "JAX_PLATFORMS": "cpu"},
+    )
+    assert out.returncode == 0, out.stderr[-3000:]
+    res = json.loads(out.stdout.strip().splitlines()[-1])
+    for mode, err in res.items():
+        assert err["devices"] == 4, (mode, err)
+        assert err["loss"] < 1e-5 and err["grad"] < 1e-4, (mode, err)
+        assert err["leaf"] < 1e-4 and err["norms"] < 1e-4, (mode, err)
