@@ -3,7 +3,8 @@
 Implements Mironov et al. 2019 ("Renyi Differential Privacy of the Sampled
 Gaussian Mechanism") for integer orders, composition over steps, and the
 improved RDP->(eps, delta) conversion used by Opacus/TF-Privacy.  Pure numpy —
-this runs on the host, never inside jit.
+this runs on the host, never inside jit.  Each ``RDPAccountant.step`` is a
+``dp.accountant`` span on the profiler's host timeline.
 
 The paper's engine (Appendix E) exposes ``target_epsilon`` -> ``sigma``; we
 recover sigma by bisection on the accountant.
@@ -14,9 +15,11 @@ import math
 from typing import Sequence
 
 import numpy as np
+from jax.profiler import TraceAnnotation
 from scipy import special
 
 DEFAULT_ALPHAS = tuple(range(2, 64)) + tuple(range(64, 513, 8))
+ACCOUNTANT_SPAN = "dp.accountant"
 
 
 def rdp_gaussian(sigma: float, alphas: Sequence[int]) -> np.ndarray:
@@ -78,9 +81,10 @@ class RDPAccountant:
         # at step k replays `step(steps=k)` and must land on EXACTLY the
         # epsilon trajectory of the uninterrupted run) depends on replaying
         # the same additions in the same order
-        r = rdp_subsampled_gaussian(q, sigma, self.alphas)
-        for _ in range(steps):
-            self._rdp = self._rdp + r
+        with TraceAnnotation(ACCOUNTANT_SPAN):
+            r = rdp_subsampled_gaussian(q, sigma, self.alphas)
+            for _ in range(steps):
+                self._rdp = self._rdp + r
 
     def get_epsilon(self, delta: float) -> float:
         eps, _ = eps_from_rdp(self._rdp, self.alphas, delta)

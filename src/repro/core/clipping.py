@@ -8,6 +8,11 @@ three-stage pipeline
     factor stage   -> C_i = clip_fn(||g_i||, R) * mask     (shared)
     gradient stage -> sum_i C_i g_i                        (mode-specific)
 
+In the compiled program the norms and factor stages run under the named
+scope ``dp.norm_pass`` (each tap's norm work under ``dp.tap_norm/<tap>``,
+see ``ghost``) and the gradient stage under ``dp.second_pass``; a device
+trace attributes each op to its stage through the op's metadata.
+
 The factor stage is delegated to a **ClipPolicy** (``repro.policies``):
 ``fixed`` (the paper's flat R, the default), ``automatic`` (AUTO-S/AUTO-V
 normalization, no R), ``quantile`` (DP-adaptive R tracking a norm quantile,
@@ -94,6 +99,11 @@ MODES = (
     "ghost_taps", "fastgradclip_taps", "mixed_ghost_taps", "bk_mixed_taps",
     "non_private",
 )
+
+# stage scopes: the first pass (forward, first backward, per-sample norms,
+# clip factors) and the gradient stage (second backward or bank einsums)
+NORM_PASS_SCOPE = "dp.norm_pass"
+SECOND_PASS_SCOPE = "dp.second_pass"
 
 
 @dataclasses.dataclass(frozen=True)
@@ -336,11 +346,14 @@ class ClipExecutor:
 
     def __call__(self, params, batch, policy_state=None):
         mask = _batch_mask(batch)
-        st = self._norm_state(params, batch)
-        norms = jnp.sqrt(st.norms2)
-        pstate = policy_state if policy_state is not None else self.policy.init_state()
-        c = self._clip_factors(norms, mask, st, pstate)
-        grads = self._weighted_grads(st, c, params)
+        # the scopes are metadata only: they name each stage's ops, not change them
+        with jax.named_scope(NORM_PASS_SCOPE):
+            st = self._norm_state(params, batch)
+            norms = jnp.sqrt(st.norms2)
+            pstate = policy_state if policy_state is not None else self.policy.init_state()
+            c = self._clip_factors(norms, mask, st, pstate)
+        with jax.named_scope(SECOND_PASS_SCOPE):
+            grads = self._weighted_grads(st, c, params)
         b = st.losses.shape[0]
         rep = c.representative if hasattr(c, "representative") else c
         aux = {"per_sample_norms": norms, "clip_factors": rep}

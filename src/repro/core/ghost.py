@@ -6,6 +6,10 @@ per-sample squared gradient norm on the branch the layerwise decision picked
 (Alg. 1), and — for the book-keeping mode — the weighted gradient
 ``sum_i C_i g_i`` directly as an einsum, skipping the second backward pass.
 
+Each tap's norm work (``tap_norm_sq``, ``tap_bank``) runs under the named
+scopes ``dp.tap_norm/<tap>`` (the weight's path, dots for slashes), so a
+device trace can tell the per-tap norm ops, pads and kernel calls apart.
+
 Three call sites:
 - ``tap_norm_sq``        per-sample norm^2 from explicit (a, g) pairs; used
                          by the reference ``*_taps`` engine and the fused
@@ -31,6 +35,7 @@ Canonical layouts (stack dims folded into the row dim N):
 """
 from __future__ import annotations
 
+import contextlib
 import math
 from typing import Mapping, Optional
 
@@ -52,6 +57,15 @@ MAX_EXACT_FP32_ID = 1 << 24
 # tuner ClipPlan ("pallas" | "xla" per dispatch op); None defers to
 # repro.kernels.dispatch's backend default (pallas on TPU, xla elsewhere).
 KernelChoices = Optional[Mapping[str, str]]
+
+TAP_NORM_SCOPE = "dp.tap_norm"
+
+
+@contextlib.contextmanager
+def _tap_norm_scope(meta: TapMeta):
+    """Named scopes ``dp.tap_norm/<tap>`` around one tap's norm work."""
+    with jax.named_scope(TAP_NORM_SCOPE), jax.named_scope(meta.param_path.replace("/", ".")):
+        yield
 
 
 def _check_embedding_vocab(meta: TapMeta, where: str) -> None:
@@ -119,6 +133,19 @@ def tap_norm_sq(
     separately as ``psg_b`` and adds its norm from the bank).  ``kernels``
     picks the Pallas-vs-XLA impl per dispatch op (also cost-only).
     """
+    with _tap_norm_scope(meta):
+        return _tap_norm_sq(
+            meta, a, g, mode=mode, decision_by=decision_by, ghost_block=ghost_block,
+            inst_block_d=inst_block_d, override=override, include_bias=include_bias,
+            kernels=kernels,
+        )
+
+
+def _tap_norm_sq(
+    meta: TapMeta, a: Optional[jax.Array], g: jax.Array, *, mode: str, decision_by: str,
+    ghost_block: int, inst_block_d: int, override: Optional[str], include_bias: bool,
+    kernels: KernelChoices,
+) -> jax.Array:
     g = g.astype(jnp.float32)
     total = jnp.zeros((meta.batch_size,), jnp.float32)
 
@@ -285,7 +312,7 @@ def tap_bank(
       pD per sample exactly when the branch rule banked it), from which both
       the ghost norm (here) and the weighted einsum (later) are formed.
     """
-    if mode != "bk_mixed":
+    if mode != "bk_mixed":  # tap_norm_sq scopes its own work
         return {
             "n": tap_norm_sq(
                 meta, a, g, mode=mode, decision_by=decision_by,
@@ -294,53 +321,54 @@ def tap_bank(
             )
         }
 
-    b = meta.batch_size
-    g32 = g.astype(jnp.float32)
-    bank: dict[str, jax.Array] = {}
-    n = jnp.zeros((b,), jnp.float32)
+    with _tap_norm_scope(meta):
+        b = meta.batch_size
+        g32 = g.astype(jnp.float32)
+        bank: dict[str, jax.Array] = {}
+        n = jnp.zeros((b,), jnp.float32)
 
-    if meta.kind == "matmul":
-        branch = decide(meta, mode="bk_mixed", by=decision_by, override=override)
-        if branch == "instantiate":
-            psg = _matmul_psg(meta, a, g32)
+        if meta.kind == "matmul":
+            branch = decide(meta, mode="bk_mixed", by=decision_by, override=override)
+            if branch == "instantiate":
+                psg = _matmul_psg(meta, a, g32)
+                bank["psg"] = psg
+                n = n + jnp.sum(jnp.square(psg).reshape(b, -1), axis=-1)
+            else:
+                bank["a"], bank["g"] = a, g
+                n = n + _tap_norm_sq(
+                    meta, a, g, mode="ghost", decision_by=decision_by,
+                    ghost_block=ghost_block, inst_block_d=inst_block_d,
+                    override=None, include_bias=False, kernels=kernels,
+                )
+        elif meta.kind == "embedding":
+            # a is the fp32-cast ids (taps.Ctx casts before probing): exact for
+            # vocab indices below 2^24 — guarded at trace time, since anything
+            # larger would silently corrupt high token ids in the bank
+            _check_embedding_vocab(meta, "the book-keeping bank")
+            bank["a"], bank["g"] = a, g
+            n = n + _tap_norm_sq(
+                meta, a, g, mode=mode, decision_by=decision_by,
+                ghost_block=ghost_block, inst_block_d=inst_block_d,
+                override=None, include_bias=False, kernels=kernels,
+            )
+        else:
+            psg = _small_psg(meta, a, g32)
             bank["psg"] = psg
             n = n + jnp.sum(jnp.square(psg).reshape(b, -1), axis=-1)
-        else:
-            bank["a"], bank["g"] = a, g
-            n = n + tap_norm_sq(
-                meta, a, g, mode="ghost", decision_by=decision_by,
-                ghost_block=ghost_block, inst_block_d=inst_block_d,
-                include_bias=False, kernels=kernels,
-            )
-    elif meta.kind == "embedding":
-        # a is the fp32-cast ids (taps.Ctx casts before probing): exact for
-        # vocab indices below 2^24 — guarded at trace time, since anything
-        # larger would silently corrupt high token ids in the bank
-        _check_embedding_vocab(meta, "the book-keeping bank")
-        bank["a"], bank["g"] = a, g
-        n = n + tap_norm_sq(
-            meta, a, g, mode=mode, decision_by=decision_by,
-            ghost_block=ghost_block, inst_block_d=inst_block_d,
-            include_bias=False, kernels=kernels,
-        )
-    else:
-        psg = _small_psg(meta, a, g32)
-        bank["psg"] = psg
-        n = n + jnp.sum(jnp.square(psg).reshape(b, -1), axis=-1)
 
-    if meta.bias_path is not None:
-        if "g" in bank:
-            # the book already reconstructs the bias grad; only the norm term
-            # is still owed (tap_norm_sq above ran with include_bias=False)
-            gf = g32.reshape(b, -1, meta.p)
-            bias_grad = jnp.sum(gf, axis=1)
-            n = n + jnp.sum(bias_grad * bias_grad, axis=-1)
-        else:
-            psg_b = jnp.sum(g32.reshape(b, -1, meta.p), axis=1)
-            bank["psg_b"] = psg_b
-            n = n + jnp.sum(psg_b * psg_b, axis=-1)
-    bank["n"] = n
-    return bank
+        if meta.bias_path is not None:
+            if "g" in bank:
+                # the book already reconstructs the bias grad; only the norm term
+                # is still owed (tap_norm_sq above ran with include_bias=False)
+                gf = g32.reshape(b, -1, meta.p)
+                bias_grad = jnp.sum(gf, axis=1)
+                n = n + jnp.sum(bias_grad * bias_grad, axis=-1)
+            else:
+                psg_b = jnp.sum(g32.reshape(b, -1, meta.p), axis=1)
+                bank["psg_b"] = psg_b
+                n = n + jnp.sum(psg_b * psg_b, axis=-1)
+        bank["n"] = n
+        return bank
 
 
 def _finish_matmul_grad(

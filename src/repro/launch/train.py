@@ -59,6 +59,7 @@ from repro.launch.steps import (
 )
 from repro.obs import events as obs
 from repro.obs.profile import ProfileWindow
+from repro.obs.timeline import STEP_ANNOTATION
 from repro.optim import adam, warmup_cosine
 from repro.parallel.reshard import use_reshard_rules
 from repro.parallel.sharding import batch_shardings, state_shardings
@@ -136,8 +137,9 @@ def parse_args(argv=None):
                          "Read back with `python -m repro.obs DIR`")
     ap.add_argument("--profile-steps", default=None, metavar="N[:M]",
                     help="capture a jax.profiler trace around the inclusive "
-                         "step window [N, M] into <obs-dir>/profile "
-                         "(repro.obs.timeline extracts per-step wall times)")
+                         "step window [N, M] into <obs-dir>/profile; each step "
+                         "is a dp.train_step annotation there, whose wall times "
+                         "`python -m repro.obs DIR --timeline` prints")
     ap.add_argument("--tune", action="store_true",
                     help="profile ghost-vs-instantiate per tap and search the "
                          "max physical microbatch before training")
@@ -565,77 +567,80 @@ def run_once(args, injection: Optional[InjectionPlan] = None) -> int:
     step = start_step
     try:
         while step < args.steps:
-            if accum == 1:
-                step_idx, batch = pipeline.next()
-                watchdog.start_step()
-                injection.on_step(step_idx)
-                if profile is not None:
-                    profile.before_step(step_idx)
-                state, metrics = jit_step(state, batch)
-            else:
-                watchdog.start_step()
-                step_idx = step
-                injection.on_step(step_idx)
-                if profile is not None:
-                    profile.before_step(step_idx)
-                # every microstep is async dispatch into the donated
-                # accumulator; nothing on the host reads a device value, so
-                # the bank reductions of microstep i overlap the dispatch
-                # (and compute) of microstep i+1
-                acc = init_fn()
-                for i in range(accum):
-                    _, batch = pipeline.next()
-                    acc = micro_fn(
-                        state["params"], state["policy"], acc, batch, idx_dev[i]
+            # one annotation per logical step on the profiler's host timeline,
+            # keyed by step number (`python -m repro.obs DIR --timeline` reads
+            # it); the trace window opens before it and closes after it, so
+            # every profiled step carries one.  The pipeline's index is `step`.
+            if profile is not None:
+                profile.before_step(step)
+            with jax.profiler.StepTraceAnnotation(STEP_ANNOTATION, step_num=step):
+                if accum == 1:
+                    step_idx, batch = pipeline.next()
+                    watchdog.start_step()
+                    injection.on_step(step_idx)
+                    state, metrics = jit_step(state, batch)
+                else:
+                    watchdog.start_step()
+                    step_idx = step
+                    injection.on_step(step_idx)
+                    # every microstep is async dispatch into the donated
+                    # accumulator; nothing on the host reads a device value, so
+                    # the bank reductions of microstep i overlap the dispatch
+                    # (and compute) of microstep i+1
+                    acc = init_fn()
+                    for i in range(accum):
+                        _, batch = pipeline.next()
+                        acc = micro_fn(
+                            state["params"], state["policy"], acc, batch, idx_dev[i]
+                        )
+                    state, metrics = fin_fn(state, acc)
+                # the ONE host sync per logical batch: bounds the dispatch
+                # queue and makes the watchdog (and step_s) time executed work,
+                # not the enqueue.  The step metrics ride the SAME sync, so the
+                # record below reads already-materialized buffers —
+                # instrumentation adds no second block_until_ready (test-asserted)
+                jax.block_until_ready((state["step"], metrics))
+                engine.record_step()
+                engine.check_epsilon_alarm(args.epsilon_alarm_frac, step=step_idx + 1)
+                dt = watchdog.end_step(step_idx)
+                step = step_idx + 1
+                if obs.metrics_active():
+                    eps_m, delta_m = engine.privacy_spent()
+                    obs.emit_metrics(
+                        {
+                            "kind": "train_step",
+                            "loss": float(metrics["loss"]),
+                            "lr": float(metrics["lr"]),
+                            "clip_frac": float(metrics["clip_frac"]),
+                            "norm_mean": float(metrics["norm_mean"]),
+                            "norm_max": float(metrics["norm_max"]),
+                            "epsilon": eps_m,
+                            "delta": delta_m,
+                            "step_s": dt,
+                            "examples_per_s": logical_eff / dt if dt > 0 else None,
+                            "physical_batch": physical,
+                            "accumulation_steps": accum,
+                            "mode": clip_mode,
+                        },
+                        step=step,
                     )
-                state, metrics = fin_fn(state, acc)
-            # the ONE host sync per logical batch: bounds the dispatch
-            # queue and makes the watchdog (and step_s) time executed work,
-            # not the enqueue.  The step metrics ride the SAME sync, so the
-            # record below reads already-materialized buffers —
-            # instrumentation adds no second block_until_ready (test-asserted)
-            jax.block_until_ready((state["step"], metrics))
-            engine.record_step()
-            engine.check_epsilon_alarm(args.epsilon_alarm_frac, step=step_idx + 1)
-            dt = watchdog.end_step(step_idx)
-            step = step_idx + 1
+                if step % args.log_every == 0 or step == args.steps:
+                    eps, delta = engine.privacy_spent()
+                    log.info(
+                        "step %d loss=%.4f lr=%.2e clip_frac=%.2f eps=%.3f (%.2fs/step)",
+                        step, float(metrics["loss"]), float(metrics["lr"]),
+                        float(metrics["clip_frac"]), eps, dt,
+                    )
+                if manager is not None:
+                    if preempt.preempted():
+                        manager.save(step, state, force=True)
+                        manager.wait()
+                        log.warning("preempted: checkpointed step %d, exiting", step)
+                        obs.emit_event("preemption", step=step, checkpointed=True)
+                        return 0
+                    manager.save(step, state)
             if profile is not None:
                 profile.after_step(step_idx)
-            if obs.metrics_active():
-                eps_m, delta_m = engine.privacy_spent()
-                obs.emit_metrics(
-                    {
-                        "kind": "train_step",
-                        "loss": float(metrics["loss"]),
-                        "lr": float(metrics["lr"]),
-                        "clip_frac": float(metrics["clip_frac"]),
-                        "norm_mean": float(metrics["norm_mean"]),
-                        "norm_max": float(metrics["norm_max"]),
-                        "epsilon": eps_m,
-                        "delta": delta_m,
-                        "step_s": dt,
-                        "examples_per_s": logical_eff / dt if dt > 0 else None,
-                        "physical_batch": physical,
-                        "accumulation_steps": accum,
-                        "mode": clip_mode,
-                    },
-                    step=step,
-                )
-            if step % args.log_every == 0 or step == args.steps:
-                eps, delta = engine.privacy_spent()
-                log.info(
-                    "step %d loss=%.4f lr=%.2e clip_frac=%.2f eps=%.3f (%.2fs/step)",
-                    step, float(metrics["loss"]), float(metrics["lr"]),
-                    float(metrics["clip_frac"]), eps, dt,
-                )
-            if manager is not None:
-                if preempt.preempted():
-                    manager.save(step, state, force=True)
-                    manager.wait()
-                    log.warning("preempted: checkpointed step %d, exiting", step)
-                    obs.emit_event("preemption", step=step, checkpointed=True)
-                    return 0
-                manager.save(step, state)
     finally:
         pipeline.stop()
         preempt.uninstall()

@@ -8,7 +8,8 @@
 ``--json`` emits the machine summary instead of text; ``--require-epsilon``
 exits non-zero when no epsilon trajectory was recorded (the tier-1 smoke
 gate's assertion); ``--timeline`` additionally extracts per-step wall
-times from a captured profiler trace under ``RUN_DIR/profile``.
+times from a captured profiler trace under ``RUN_DIR/profile`` (the train
+loop's ``dp.train_step`` annotations, else ``--step-pattern`` spans).
 
 Deliberately jax-free: reading a run's telemetry must work on a laptop
 that cannot even initialize the run's backend.
@@ -23,8 +24,9 @@ import sys
 from repro.obs.report import render_text, summarize_run
 from repro.obs.timeline import (
     DEFAULT_STEP_PATTERN,
+    STEP_ANNOTATION,
     percentile,
-    step_wall_times_ms,
+    step_timeline,
 )
 
 
@@ -39,17 +41,20 @@ def main(argv=None) -> int:
     ap.add_argument("--timeline", action="store_true",
                     help="extract per-step wall times from the profiler "
                          "trace under RUN_DIR/profile")
-    ap.add_argument("--step-pattern", default=DEFAULT_STEP_PATTERN,
+    ap.add_argument("--step-pattern", default=None,
                     help="regex over trace event names that count as "
-                         "step/execution spans")
+                         "step/execution spans (default: the program's "
+                         f"{STEP_ANNOTATION} annotations, else "
+                         f"{DEFAULT_STEP_PATTERN!r})")
     args = ap.parse_args(argv)
 
     summary = summarize_run(args.run_dir)
     if args.timeline:
-        times = step_wall_times_ms(
+        times, source = step_timeline(
             pathlib.Path(args.run_dir) / "profile", pattern=args.step_pattern
         )
         summary["profile_step_times_ms"] = times
+        summary["profile_step_source"] = source
         summary["profile_step_p50_ms"] = (
             percentile(times, 0.50) if times else None
         )
@@ -61,8 +66,11 @@ def main(argv=None) -> int:
         if args.timeline:
             times = summary["profile_step_times_ms"]
             if times:
+                what = (f"{STEP_ANNOTATION} annotation(s)"
+                        if summary["profile_step_source"] == "annotations"
+                        else "span group(s)")
                 print(
-                    f"  profiled steps: {len(times)} span group(s), "
+                    f"  profiled steps: {len(times)} {what}, "
                     f"p50 {percentile(times, 0.5):.1f}ms "
                     f"p95 {percentile(times, 0.95):.1f}ms"
                 )

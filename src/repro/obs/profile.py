@@ -1,13 +1,13 @@
 """Profiler trace capture around a step window (``--profile-steps N:M``).
 
 ``jax.profiler.start_trace`` / ``stop_trace`` bracket the inclusive step
-range ``[N, M]``: the trace opens before step N dispatches and closes after
-step M's work is synced, so the captured window contains exactly M-N+1
-logical batches of device execution.  On TPU the
-``--xla_step_marker_location=1`` groundwork (``launch/env.py``) makes XLA
-mark each outer-loop step inside that window; on CPU/GPU the
-``PjRtCpuExecutable::Execute`` / module events carry the same information
-(``repro.obs.timeline`` extracts either).
+range ``[N, M]``: the trace opens before step N begins and closes after
+step M's work is done, so the captured window contains exactly M-N+1
+logical batches.  The train loop marks each of them with a
+``dp.train_step`` step annotation carrying its step number, on every
+backend; ``repro.obs.timeline`` reads those first, and falls back to the
+backend's execution events (``PjRtCpuExecutable::Execute`` on CPU, module
+events on GPU) for traces without them.
 
 The window degrades gracefully: a backend whose profiler cannot start
 (sandboxed CI, missing permissions) logs a warning and the run proceeds
@@ -44,8 +44,8 @@ def parse_window(spec: str) -> tuple[int, int]:
 class ProfileWindow:
     """Drives one start_trace/stop_trace pair from the train loop.
 
-    The loop calls ``before_step(step)`` ahead of dispatch and
-    ``after_step(step)`` once the step's sync point has passed; ``stop()``
+    The loop calls ``before_step(step)`` ahead of the step's annotation
+    and ``after_step(step)`` once the annotation has closed; ``stop()``
     (idempotent) runs in the loop's ``finally`` so a crash inside the
     window still flushes a usable partial trace.
     """

@@ -7,10 +7,14 @@ Updates are ADDED to params via ``apply_updates`` (they carry the -lr sign).
 
 DP-SGD / DP-Adam are these optimizers fed the privatized gradient (Eq. 2.1):
 the mechanism lives entirely in the gradient, as in the paper.
+
+Every ``update`` and ``apply_updates`` runs under the named scope
+``dp.update``, which tags the optimizer's ops on a device trace.
 """
 from __future__ import annotations
 
 import dataclasses
+import functools
 from typing import Any, Callable
 
 import jax
@@ -20,6 +24,16 @@ Params = Any
 State = Any
 Schedule = Callable[[jax.Array], jax.Array]
 
+UPDATE_SCOPE = "dp.update"
+
+
+def _scoped(fn: Callable) -> Callable:
+    @functools.wraps(fn)
+    def run(*args, **kwargs):
+        with jax.named_scope(UPDATE_SCOPE):
+            return fn(*args, **kwargs)
+    return run
+
 
 @dataclasses.dataclass(frozen=True)
 class Optimizer:
@@ -27,6 +41,7 @@ class Optimizer:
     update: Callable[..., tuple[Params, State]]
 
 
+@_scoped
 def apply_updates(params: Params, updates: Params) -> Params:
     return jax.tree_util.tree_map(
         lambda p, u: (p.astype(jnp.float32) + u.astype(jnp.float32)).astype(p.dtype),
@@ -41,6 +56,7 @@ def sgd(momentum: float = 0.0, nesterov: bool = False) -> Optimizer:
             return {}
         return {"m": jax.tree_util.tree_map(lambda p: jnp.zeros_like(p, jnp.float32), params)}
 
+    @_scoped
     def update(grads, state, params, step, lr):
         del params, step
         if momentum == 0.0:
@@ -76,6 +92,7 @@ def adam(
             "v": jax.tree_util.tree_map(z, params),
         }
 
+    @_scoped
     def update(grads, state, params, step, lr):
         t = step.astype(jnp.float32) + 1.0
         c1 = 1.0 - b1**t

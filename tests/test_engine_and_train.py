@@ -90,6 +90,32 @@ def test_train_cli_resume_from_pre_policy_checkpoint(tmp_path):
         assert int(z["policy/step"]) == 2
 
 
+def test_train_cli_profile_window_marks_steps_and_accounting(tmp_path, capsys):
+    """--profile-steps 1:2 traces exactly steps 1 and 2, each under its
+    dp.train_step annotation with one dp.accountant span inside, and the
+    obs CLI's timeline reads the two steps back."""
+    from repro.launch.train import main
+    from repro.obs.__main__ import main as obs_cli
+    from repro.obs.timeline import annotated_steps, load_trace_events
+
+    argv = [
+        "--arch", "yi-6b", "--reduced", "--steps", "4", "--batch", "2",
+        "--seq", "16", "--obs-dir", str(tmp_path), "--profile-steps", "1:2",
+        "--log-every", "4",
+    ]
+    assert main(argv) == 0
+    prof = tmp_path / "profile"
+    steps = annotated_steps(prof)
+    assert [s["step"] for s in steps] == [1, 2]
+    accounting = [e for e in load_trace_events(prof) if e.get("name") == "dp.accountant"]
+    assert len(accounting) == 2
+    for s, a in zip(steps, sorted(accounting, key=lambda e: e["ts"])):
+        assert s["ts_us"] <= a["ts"] and a["ts"] + a["dur"] <= s["ts_us"] + s["dur_us"]
+    capsys.readouterr()
+    assert obs_cli([str(tmp_path), "--timeline"]) == 0
+    assert "profiled steps: 2 dp.train_step annotation(s)" in capsys.readouterr().out
+
+
 def test_train_cli_poisson(tmp_path):
     from repro.launch.train import main
 

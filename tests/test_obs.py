@@ -38,7 +38,15 @@ from repro.obs import (
 from repro.obs import events as obs_events
 from repro.obs.profile import ProfileWindow, parse_window
 from repro.obs.report import render_text
-from repro.obs.timeline import execution_spans, percentile, step_wall_times_ms
+from repro.obs.timeline import (
+    STEP_ANNOTATION,
+    annotated_steps,
+    execution_spans,
+    percentile,
+    step_timeline,
+    step_wall_times_ms,
+    trace_files,
+)
 from repro.runtime.inject import InjectionPlan, tear_file
 
 ARCH = ["--arch", "yi-6b", "--reduced", "--seq", "16", "--log-every", "4"]
@@ -324,6 +332,52 @@ def test_timeline_groups_synthetic_trace(tmp_path):
     assert percentile([], 0.5) == 0.0
 
 
+def _write_trace(root, events):
+    d = root / "plugins" / "profile" / "2026"
+    d.mkdir(parents=True)
+    (d / "host.trace.json.gz").write_bytes(
+        gzip.compress(json.dumps({"traceEvents": events}).encode())
+    )
+
+
+def test_timeline_reads_the_programs_step_annotations(tmp_path):
+    step = STEP_ANNOTATION
+    _write_trace(tmp_path, [
+        # steps out of order, one of them on two hosts
+        {"ph": "X", "name": step, "ts": 5000, "dur": 900, "args": {"step_num": "8"}},
+        {"ph": "X", "name": step, "ts": 0, "dur": 4000, "args": {"step_num": "7"}},
+        {"ph": "X", "name": step, "ts": 100, "dur": 4200, "args": {"step_num": "7"}},
+        {"ph": "X", "name": "PjRtCpuExecutable::Execute", "ts": 10, "dur": 100},
+        {"ph": "X", "name": "PjRtCpuExecutable::Execute", "ts": 5010, "dur": 100},
+        # no step number: not a step
+        {"ph": "X", "name": step, "ts": 9000, "dur": 10},
+    ])
+    assert [a["step"] for a in annotated_steps(tmp_path)] == [7, 8]
+    times, source = step_timeline(tmp_path)
+    assert source == "annotations" and times == pytest.approx([4.3, 0.9])
+    # an explicit pattern reads the execution spans instead
+    times, source = step_timeline(tmp_path, pattern="PjRtCpuExecutable::Execute")
+    assert source == "spans" and len(times) == 2
+
+
+def test_profile_window_captures_step_annotations(tmp_path):
+    import jax
+    import jax.numpy as jnp
+
+    win = ProfileWindow(1, 2, tmp_path / "profile")
+    f = jax.jit(lambda x: x @ x)
+    x = jnp.ones((32, 32))
+    for step in range(4):
+        win.before_step(step)
+        with jax.profiler.StepTraceAnnotation(STEP_ANNOTATION, step_num=step):
+            jax.block_until_ready(f(x))
+        win.after_step(step)
+    if not win.done or not trace_files(tmp_path / "profile"):
+        pytest.skip("profiler unavailable on this backend")
+    assert [a["step"] for a in annotated_steps(tmp_path / "profile")] == [1, 2]
+    assert len(step_wall_times_ms(tmp_path / "profile")) == 2
+
+
 # -- report + CLI ----------------------------------------------------------
 def _fake_run_dir(tmp_path):
     configure_run(tmp_path, run_id="run-x")
@@ -379,6 +433,23 @@ def test_obs_cli_timeline_renders_profile(tmp_path, capsys):
     ]}))
     assert cli([str(d), "--timeline"]) == 0
     assert "profiled steps: 1 span group" in capsys.readouterr().out
+
+
+def test_obs_cli_timeline_renders_annotated_steps(tmp_path, capsys):
+    from repro.obs.__main__ import main as cli
+
+    d = _fake_run_dir(tmp_path)
+    _write_trace(d / "profile", [
+        {"ph": "X", "name": STEP_ANNOTATION, "ts": 1000 * k, "dur": 800,
+         "args": {"step_num": str(k)}} for k in range(3)
+    ])
+    assert cli([str(d), "--timeline"]) == 0
+    out = capsys.readouterr().out
+    assert "profiled steps: 3 dp.train_step annotation(s), p50 0.8ms" in out
+    assert cli([str(d), "--timeline", "--json"]) == 0
+    summary = json.loads(capsys.readouterr().out)
+    assert summary["profile_step_source"] == "annotations"
+    assert summary["profile_step_times_ms"] == [0.8, 0.8, 0.8]
 
 
 # -- logging satellites ----------------------------------------------------
