@@ -371,24 +371,6 @@ def tap_bank(
         return bank
 
 
-def _finish_matmul_grad(
-    meta: TapMeta, w: jax.Array, param_shape: tuple[int, ...]
-) -> jax.Array:
-    """Weighted matmul grad (L, G, D, p) -> the parameter's own layout.
-
-    Convolution weights live as (kh, kw, d, p) while the unfolded fan-in is
-    channel-major (D = d*kh*kw), so the conv path un-permutes before the
-    final reshape.
-    """
-    lead = math.prod(meta.stack_dims) if meta.stack_dims else 1
-    if meta.conv is not None:
-        # unfold ordering is channel-major: (D=d*kh*kw, p) -> (d, kh, kw, p)
-        kh, kw = meta.conv.kernel
-        d_in = meta.D // (kh * kw)
-        w = w.reshape(lead, d_in, kh, kw, meta.p).transpose(0, 2, 3, 1, 4)
-    return w.reshape(param_shape)
-
-
 def tap_weighted_grads(
     meta: TapMeta,
     a: Optional[jax.Array],
@@ -435,7 +417,8 @@ def tap_weighted_grads(
         w = dispatch.book_weighted_grad(
             a2, g2, w2, impl=dispatch.kernels_arg(kernels, "psg_contract")
         ).reshape(lead, gdim, meta.D, meta.p)
-        out[meta.param_path] = _finish_matmul_grad(meta, w, param_shape)
+        # a conv's unfolded fan-in is offset-major, its weight's own order
+        out[meta.param_path] = w.reshape(param_shape)
     elif meta.kind == "embedding":
         ids = a.reshape(-1)
         flat_g = gw.reshape(-1, meta.p)

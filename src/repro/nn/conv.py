@@ -1,7 +1,7 @@
 """Convolution layers with DP taps (the paper's central case).
 
 ``Conv2d`` records its *raw* input plus unfold metadata; the DP engine unfolds
-lazily (im2col via ``lax.conv_general_dilated_patches``) only on the branch the
+lazily (im2col, ``unfold2d``) only on the branch the
 layerwise decision selects, so the forward pass stays on the fused conv op.
 
 ``DepthwiseConv1d`` (Mamba/xLSTM frontends) records the unfolded input
@@ -23,22 +23,36 @@ from repro.parallel.reshard import reshard_param
 
 
 def unfold2d(x: jax.Array, info: ConvInfo) -> jax.Array:
-    """U(a): (B, H, W, d) -> (B, H_out*W_out, d*kh*kw).
+    """U(a): (B, H, W, d) -> (B, H_out*W_out, kh*kw*d).
 
-    Feature ordering follows ``conv_general_dilated_patches`` which is
-    channel-major: index = c * (kh*kw) + kh_i * kw + kw_i.  Weights reshaped
-    as (d, kh, kw, p) -> (d*kh*kw, p) match this ordering.
+    Features are offset-major, index = (kh_i * kw + kw_i) * d + c: one
+    strided slice of the padded input per kernel offset, joined along the
+    channels.  This is the conv weight's own (kh, kw, d, p) order, so a
+    (kh*kw*d, p) gradient reshapes to the weight with no transpose; and the
+    channels stay minor, so on a TPU the patches leave one fusion in the
+    row-major layout the Pallas ghost norm reads.
     """
-    patches = lax.conv_general_dilated_patches(
-        x,
-        filter_shape=info.kernel,
-        window_strides=info.strides,
-        padding=info.padding,
-        rhs_dilation=info.rhs_dilation,
-        dimension_numbers=("NHWC", "HWIO", "NHWC"),
-    )
-    b = x.shape[0]
-    return patches.reshape(b, -1, patches.shape[-1])
+    kh, kw = info.kernel
+    sh, sw = info.strides
+    dh, dw = info.rhs_dilation or (1, 1)
+    b, h, w, d = x.shape
+    pads = info.padding
+    if isinstance(pads, str):
+        pads = lax.padtype_to_pads(
+            (h, w), ((kh - 1) * dh + 1, (kw - 1) * dw + 1), (sh, sw), pads
+        )
+    (top, bottom), (left, right) = pads
+    xp = lax.pad(x, jnp.zeros((), x.dtype), ((0, 0, 0), (top, bottom, 0), (left, right, 0),
+                                             (0, 0, 0)))
+    ho = (xp.shape[1] - (kh - 1) * dh - 1) // sh + 1
+    wo = (xp.shape[2] - (kw - 1) * dw - 1) // sw + 1
+    cols = [
+        lax.slice(xp, (0, i * dh, j * dw, 0),
+                  (b, i * dh + (ho - 1) * sh + 1, j * dw + (wo - 1) * sw + 1, d),
+                  (1, sh, sw, 1))
+        for i in range(kh) for j in range(kw)
+    ]
+    return jnp.concatenate(cols, axis=-1).reshape(b, ho * wo, kh * kw * d)
 
 
 class Conv2d(Module):

@@ -135,6 +135,43 @@ def test_conv2d_exactness():
     _assert_matches(_run_all_modes(loss, params, batch))
 
 
+@pytest.mark.parametrize("kernel,strides,padding,dilation", [
+    ((3, 3), (1, 1), "SAME", None),
+    ((3, 3), (2, 2), "SAME", None),
+    ((3, 2), (1, 2), "VALID", None),
+    ((4, 4), (4, 4), "VALID", None),
+    ((3, 3), (1, 1), ((2, 0), (1, 3)), None),
+    ((3, 3), (1, 1), "SAME", (2, 2)),
+    ((2, 3), (2, 1), "SAME", (1, 2)),
+])
+def test_unfold2d_is_offset_major(kernel, strides, padding, dilation):
+    """unfold2d holds the conv's patches with features (kh_i*kw + kw_i)*d + c,
+    the weight's own (kh, kw, d, p) order: patches x weight give the conv."""
+    from repro.core.taps import ConvInfo
+    from repro.nn.conv import unfold2d
+
+    info = ConvInfo(kernel, strides, padding, rhs_dilation=dilation)
+    kx, kw = jax.random.split(jax.random.PRNGKey(6))
+    x = jax.random.normal(kx, (2, 9, 11, 5))
+    w = jax.random.normal(kw, kernel + (5, 4))
+    dn = ("NHWC", "HWIO", "NHWC")
+    got = unfold2d(x, info)
+    # conv_general_dilated_patches orders the features channel-major
+    want = jax.lax.conv_general_dilated_patches(
+        x, kernel, strides, padding, rhs_dilation=dilation, dimension_numbers=dn
+    )
+    n, ho, wo, _ = want.shape
+    want = want.reshape(n, ho * wo, 5, -1).transpose(0, 1, 3, 2).reshape(got.shape)
+    assert jnp.array_equal(got, want)
+    conv = jax.lax.conv_general_dilated(
+        x, w, strides, padding, rhs_dilation=dilation, dimension_numbers=dn,
+        precision=jax.lax.Precision.HIGHEST,
+    )
+    via = jnp.einsum("ntk,kp->ntp", got, w.reshape(-1, 4),
+                     precision=jax.lax.Precision.HIGHEST)
+    assert jnp.allclose(via, conv.reshape(via.shape), rtol=1e-5, atol=1e-5)
+
+
 class _StackModel(Module):
     def __init__(self):
         d = 16

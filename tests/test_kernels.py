@@ -10,6 +10,7 @@ from repro.kernels.ghost_norm import ops as gops
 from repro.kernels.ghost_norm.ghost_norm import (
     embedding_ghost_norm_sq_pallas,
     ghost_norm_sq_pallas,
+    ghost_tiling,
 )
 from repro.kernels.ghost_norm.ref import (
     embedding_ghost_norm_sq_ref,
@@ -35,32 +36,85 @@ requires_tpu = pytest.mark.skipif(
 )
 
 
+def _ghost_case(n, t, d, p, dt, block_t=32):
+    return pytest.param(n, t, d, p, dt, block_t, id=f"{n}-{t}-{d}-{p}-{jnp.dtype(dt).name}")
+
+
 GHOST_SHAPES = [
-    (3, 64, 16, 24, jnp.float32),
-    (2, 100, 33, 7, jnp.float32),
-    (1, 256, 128, 64, jnp.bfloat16),
-    (4, 32, 8, 130, jnp.float32),
+    # tiled: T >= block_t = 32, T and the features padded in HBM
+    _ghost_case(3, 64, 16, 24, jnp.float32),
+    _ghost_case(2, 100, 33, 7, jnp.float32),
+    _ghost_case(1, 256, 128, 64, jnp.bfloat16),
+    _ghost_case(4, 32, 8, 130, jnp.float32),
+    # packed: T < block_t = 256; N a multiple of bn or not (a ragged last
+    # step), widths lane multiples or not (27k, p = 10), chunks of 128 lanes
+    _ghost_case(300, 1, 512, 10, jnp.float32, 256),
+    _ghost_case(128, 4, 256, 128, jnp.float32, 256),
+    _ghost_case(70, 4, 270, 10, jnp.bfloat16, 256),
+    _ghost_case(32, 16, 384, 256, jnp.float32, 256),
+    _ghost_case(20, 16, 54, 10, jnp.float32, 256),
+    _ghost_case(9, 64, 128, 130, jnp.bfloat16, 256),
+    _ghost_case(8, 64, 216, 64, jnp.float32, 256),
+    _ghost_case(3, 197, 135, 10, jnp.bfloat16, 256),
+    _ghost_case(2, 197, 128, 64, jnp.float32, 256),
 ]
 
 
-@pytest.mark.parametrize("n,t,d,p,dt", GHOST_SHAPES)
-def test_ghost_norm_pallas_vs_ref(n, t, d, p, dt):
+@pytest.mark.parametrize("n,t,d,p,dt,block_t", GHOST_SHAPES)
+def test_ghost_norm_pallas_vs_ref(n, t, d, p, dt, block_t):
     ks = jax.random.split(jax.random.PRNGKey(t * 7 + d), 2)
     a = jax.random.normal(ks[0], (n, t, d)).astype(dt)
     g = jax.random.normal(ks[1], (n, t, p)).astype(dt)
-    got = ghost_norm_sq_pallas(a, g, block_t=32, block_f=32, interpret=True)
+    got = ghost_norm_sq_pallas(a, g, block_t=block_t, block_f=32, interpret=True)
     want = ghost_norm_sq_ref(a, g)
     assert jnp.allclose(got, want, rtol=2e-4), float(jnp.max(jnp.abs(got - want)))
 
 
-@pytest.mark.parametrize("n,t,d,p,dt", GHOST_SHAPES)
-def test_ghost_norm_chunked_vs_ref(n, t, d, p, dt):
+@pytest.mark.parametrize("n,t,d,p,dt,block_t", GHOST_SHAPES)
+def test_ghost_norm_chunked_vs_ref(n, t, d, p, dt, block_t):
     ks = jax.random.split(jax.random.PRNGKey(n * 31 + p), 2)
     a = jax.random.normal(ks[0], (n, t, d)).astype(dt)
     g = jax.random.normal(ks[1], (n, t, p)).astype(dt)
-    got = gops.ghost_norm_sq(a, g, block=32)
+    got = gops.ghost_norm_sq(a, g, block=block_t)
     want = ghost_norm_sq_ref(a, g)
     assert jnp.allclose(got, want, rtol=2e-4)
+
+
+# (N, T, D, p) of the taps the ghost norm takes in the benchmark's cells,
+# written out from their tap discovery: VGG19 on CIFAR-10 at batch 1024
+# (conv2_2, conv3_1..4, conv4_1..4, conv5_1..4, the head), xlstm-350m's LM
+# head at 2 x 4096 tokens
+VGG19_GHOST_TAPS = [
+    (1024, 256, 1152, 128),
+    (1024, 64, 1152, 256),
+    *[(1024, 64, 2304, 256)] * 3,
+    (1024, 16, 2304, 512),
+    *[(1024, 16, 4608, 512)] * 3,
+    *[(1024, 4, 4608, 512)] * 4,
+    (1024, 1, 512, 10),
+]
+XLSTM_LM_HEAD = (2, 4096, 1024, 50304)
+
+
+@pytest.mark.parametrize("tap", sorted(set(VGG19_GHOST_TAPS)) + [XLSTM_LM_HEAD])
+def test_ghost_tiling_of_the_cells_taps(tap):
+    n, t, d, p = tap
+    path, bn, bfa, bfg = ghost_tiling(n, t, d, p)
+    if t >= 256:  # conv2_2 and the LM head keep today's tiles
+        assert (path, bn, bfa, bfg) == ("tiled", 1, 512, 512)
+        return
+    # packed: whole samples of unpadded T filling one 256-row tile, and
+    # feature chunks that split the widths with no pad
+    assert path == "packed"
+    assert bn == 256 // t and bn * t == 256
+    assert d % bfa == 0 and p % bfg == 0
+    assert bfa == d or bfa % 128 == 0
+    assert bfg == p or bfg % 128 == 0
+
+
+def test_ghost_tiling_counts_vgg19_packed_calls():
+    paths = [ghost_tiling(*tap)[0] for tap in VGG19_GHOST_TAPS]
+    assert paths.count("packed") == 13 and paths[0] == "tiled"
 
 
 def test_ghost_norm_chunked_path_forced():
@@ -297,12 +351,27 @@ def test_dispatch_flash_attention_dynamic_args_fall_back():
 
 # ------------------------------------- compiled TPU parity (non-interpret) --
 @requires_tpu
-def test_tpu_ghost_norm_compiled_parity():
+@pytest.mark.parametrize("n,t,d,p,mxu_dtype", [
+    (4, 300, 96, 48, None),  # tiled
+    # VGG19's packed taps at N = 64, f32 as the config stores them.  The
+    # kernel's dots take the MXU's default one-pass bf16 operands, which
+    # move the head's |a|^2 |g|^2 by ~4e-3: the reference multiplies the
+    # same rounded operands, exactly
+    (64, 16, 4608, 512, jnp.bfloat16),  # conv4_2
+    (64, 4, 4608, 512, jnp.bfloat16),  # conv5_1
+    (64, 1, 512, 10, jnp.bfloat16),  # the head
+])
+def test_tpu_ghost_norm_compiled_parity(n, t, d, p, mxu_dtype):
     ks = jax.random.split(jax.random.PRNGKey(0), 2)
-    a = jax.random.normal(ks[0], (4, 300, 96))
-    g = jax.random.normal(ks[1], (4, 300, 48))
+    a = jax.random.normal(ks[0], (n, t, d))
+    g = jax.random.normal(ks[1], (n, t, p))
     got = ghost_norm_sq_pallas(a, g, interpret=False)
-    assert jnp.allclose(got, ghost_norm_sq_ref(a, g), rtol=2e-4)
+    if mxu_dtype is None:
+        want = ghost_norm_sq_ref(a, g)
+    else:
+        with jax.default_matmul_precision("highest"):
+            want = ghost_norm_sq_ref(a.astype(mxu_dtype), g.astype(mxu_dtype))
+    assert jnp.allclose(got, want, rtol=2e-4)
 
 
 @requires_tpu

@@ -62,6 +62,25 @@ CASES = {
         lambda a, g: dispatch.ghost_norm_sq(a, g),
         [((64, 16, 4608), F32), ((64, 16, 512), F32)],
     ),
+    # VGG19 on CIFAR-10, a 2x2 conv tap and the 512 -> 10 head at batch 64
+    "ghost_norm_vgg19_2x2": (
+        lambda a, g: dispatch.ghost_norm_sq(a, g),
+        [((64, 4, 4608), F32), ((64, 4, 512), F32)],
+    ),
+    "ghost_norm_vgg19_head": (
+        lambda a, g: dispatch.ghost_norm_sq(a, g),
+        [((64, 1, 512), F32), ((64, 1, 10), F32)],
+    ),
+    # VGG19's 4x4 tap with a ragged last step (100 = 6 x 16 + 4 samples)
+    "ghost_norm_vgg19_4x4_ragged": (
+        lambda a, g: dispatch.ghost_norm_sq(a, g),
+        [((100, 16, 2304), F32), ((100, 16, 512), F32)],
+    ),
+    # a ViT-L/16 tap at 224 px (197 tokens, bf16 activations): one sample a step
+    "ghost_norm_vit_l16": (
+        lambda a, g: dispatch.ghost_norm_sq(a, g),
+        [((16, 197, 1024), BF16), ((16, 197, 4096), F32)],
+    ),
     # xlstm-350m lm_head at seq 4096 (d_model 1024, vocab 50304)
     "ghost_norm_xlstm_lm_head": (
         lambda a, g: dispatch.ghost_norm_sq(a, g),
@@ -96,6 +115,23 @@ def test_kernel_compiles_for_v5e(case, one_chip, on_tpu):
     args = [jax.ShapeDtypeStruct(s, dt, sharding=one_chip) for s, dt in shapes]
     compiled = jax.jit(fn).lower(*args).compile()
     assert "tpu_custom_call" in compiled.as_text()
+
+
+@pytest.mark.parametrize("case,packed", [
+    ("ghost_norm_vgg19_2x2", True),
+    ("ghost_norm_vgg19_4x4", True),
+    ("ghost_norm_xlstm_lm_head", False),
+])
+def test_ghost_norm_call_names_its_tiling(case, packed, one_chip, on_tpu):
+    """A trace counts the packed path's calls by their name."""
+    fn, shapes = CASES[case]
+    args = [jax.ShapeDtypeStruct(s, dt, sharding=one_chip) for s, dt in shapes]
+    text = jax.jit(fn).lower(*args).compile().as_text()
+    calls = [ln for ln in text.splitlines() if "custom-call(" in ln and " = " in ln]
+    assert len(calls) == 1
+    name = calls[0].split(" = ", 1)[0]
+    assert "ghost_norm_sq_pallas" in name
+    assert ("ghost_norm_sq_pallas_packed" in name) == packed
 
 
 # the clipping ops at the VGG19 widths above, with the samples split over a
