@@ -23,6 +23,11 @@ joins the cut set.  This chain is what covers recurrent late taps: xlstm
 adds the tap to the scan *input stream* (``src/repro/nn/xlstm.py``) and the
 true pre-activation ``s = pre_t + h @ wr`` only exists inside the scan body.
 
+Tap kinds need no rules of their own here: a ``table`` tap (BEiT's relative
+position bias) adds its zeros after the gather and the broadcast over the
+batch, so the table's one route to the loss runs through that add, as a
+``bias`` tap's (the CLS token) runs through its broadcast.
+
 Per-claim passes are deliberate: one global all-cuts pass would let an
 untapped middle layer hide behind a downstream tap's cut, so each tap's
 claimed leaves are tested against that tap's cuts alone.

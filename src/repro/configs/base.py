@@ -41,7 +41,13 @@ class ArchConfig:
     rope_theta: float = 1e6
     window: Optional[int] = None  # sliding-window attention (Mixtral)
     norm: str = "rmsnorm"  # rmsnorm | layernorm
-    act: str = "swiglu"  # swiglu | gelu
+    norm_eps: Optional[float] = None  # None: the norm module's own default
+    act: str = "swiglu"  # swiglu | gelu (tanh) | gelu_erf (exact)
+    # attention biases: with qkv_bias, k_bias=False gives BEiT's q and v
+    # biases only; out_bias puts a bias on the output projection
+    k_bias: bool = True
+    out_bias: bool = False
+    layer_scale: float = 0.0  # init of the residual branches' scales; 0: none
     # MoE
     moe_experts: int = 0
     moe_top_k: int = 2

@@ -83,9 +83,10 @@ def decide(
 ) -> str:
     """Per-tap branch: 'ghost' | 'instantiate'.
 
-    Non-matmul kinds have a forced branch: scale/bias/dw_conv per-sample grads
-    are tiny (instantiate); embeddings always use the index-equality ghost
-    norm (instantiating a (V, p) gradient per sample is never viable).
+    Non-matmul kinds have a forced branch: scale/bias/dw_conv/table per-sample
+    grads are tiny (instantiate; a table's index-equality Gram would cost T^2
+    per sample against its R*p rows); embeddings always use the index-equality
+    ghost norm (instantiating a (V, p) gradient per sample is never viable).
 
     ``override`` is a measured-cost branch from a ``repro.tuner`` ClipPlan:
     it wins over the analytic Eq-(4.1) rule (both branches compute the same

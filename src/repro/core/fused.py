@@ -93,7 +93,7 @@ def bank_struct(
             banks_book = True
     elif meta.kind == "embedding":
         banks_book = True
-    elif meta.kind in ("dw_conv", "scale", "scale_grouped", "bias"):
+    elif meta.kind in ("dw_conv", "scale", "scale_grouped", "bias", "table"):
         out["psg"] = jax.ShapeDtypeStruct(
             sd + (b,) + ghost_mod.psg_param_shape(meta), f32
         )

@@ -32,6 +32,7 @@ Canonical layouts (stack dims folded into the row dim N):
 - bias:       g (N, T, p)             grad = sum_T g
 - dw_conv:    a (N, T, k, d), g (N, T, d)
 - scale_grouped: a, g (N, T, h*dh), param (h,)
+- table:      g (N, p, T), no a; param (R, p) = (D, p)
 """
 from __future__ import annotations
 
@@ -41,6 +42,7 @@ from typing import Mapping, Optional
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 from repro.core.decision import decide
 from repro.core.taps import TapMeta
@@ -112,6 +114,15 @@ def _canonical_ag(meta: TapMeta, a: jax.Array, g: jax.Array):
     return aa, gg
 
 
+def table_segment_sum(meta: TapMeta, g: jax.Array) -> jax.Array:
+    """A ``table`` tap's cotangent summed into the table's rows:
+    g (N, p, T) -> (N, R, p), row r gathering every position k with
+    index[k] = r, as one matmul against the index's (T, R) one-hot."""
+    index = jnp.asarray(np.asarray(meta.gather.index, np.int32))
+    onehot = (index[:, None] == jnp.arange(meta.D, dtype=jnp.int32)[None, :]).astype(g.dtype)
+    return jnp.einsum("nct,tr->nrc", g, onehot, preferred_element_type=jnp.float32)
+
+
 def tap_norm_sq(
     meta: TapMeta,
     a: Optional[jax.Array],
@@ -176,6 +187,10 @@ def _tap_norm_sq(
         gf = _fold(meta, g, (meta.T, meta.p))
         grad = jnp.sum(gf * af, axis=-2)  # (L, B, p)
         total = total + jnp.sum(grad * grad, axis=(0, 2))
+    elif meta.kind == "table":
+        lead = math.prod(meta.stack_dims) if meta.stack_dims else 1
+        grad = table_segment_sum(meta, g.reshape(lead * meta.batch_size, meta.p, meta.T))
+        total = total + _per_sample(meta, jnp.sum(grad * grad, axis=(1, 2)))
     elif meta.kind == "bias":
         gf = _fold(meta, g, (meta.T, meta.p))
         grad = jnp.sum(gf, axis=-2)
@@ -216,7 +231,7 @@ def psg_param_shape(meta: TapMeta) -> tuple[int, ...]:
         if meta.n_groups > 1:
             return (meta.n_groups, meta.D, meta.p)
         return (meta.D, meta.p)
-    if meta.kind == "dw_conv":
+    if meta.kind in ("dw_conv", "table"):
         return (meta.D, meta.p)
     if meta.kind in ("scale", "scale_grouped", "bias"):
         return (meta.p,)
@@ -264,7 +279,8 @@ def _small_psg(meta: TapMeta, a: jax.Array, g: jax.Array) -> jax.Array:
     """Per-layer per-sample gradients for the tiny forced-instantiate kinds.
 
     Shapes (B = batch, per layer instance, no stack dims):
-    scale (B, p) | scale_grouped (B, h) | dw_conv (B, k, d) | bias (B, p).
+    scale (B, p) | scale_grouped (B, h) | dw_conv (B, k, d) | bias (B, p) |
+    table (B, R, p).
     """
     b = meta.batch_size
     if meta.kind == "scale":
@@ -283,6 +299,8 @@ def _small_psg(meta: TapMeta, a: jax.Array, g: jax.Array) -> jax.Array:
         return jnp.einsum("btkd,btd->bkd", af, gf)
     if meta.kind == "bias":
         return jnp.sum(g.reshape(b, meta.T, meta.p), axis=1)
+    if meta.kind == "table":
+        return table_segment_sum(meta, g.reshape(b, meta.p, meta.T))
     raise ValueError(f"no small per-sample gradient for tap kind {meta.kind!r}")
 
 
@@ -429,6 +447,9 @@ def tap_weighted_grads(
         out[meta.param_path] = jnp.einsum("lbgtp,lbgtp->lp", af, gw).reshape(param_shape)
     elif meta.kind == "bias":
         out[meta.param_path] = jnp.einsum("lbgtp->lp", gw).reshape(param_shape)
+    elif meta.kind == "table":
+        gc = g.astype(jnp.float32).reshape(lead, b, meta.p, meta.T) * cw[None, :, None, None]
+        out[meta.param_path] = table_segment_sum(meta, jnp.sum(gc, axis=1)).reshape(param_shape)
     elif meta.kind == "scale_grouped":
         h, dh = meta.p, meta.D
         af = a.astype(jnp.float32).reshape(lead, meta.batch_size, meta.T, h, dh)

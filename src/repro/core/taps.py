@@ -28,6 +28,15 @@ Tap kinds and their per-sample gradient semantics
                  Per-sample grad = sum_T gs_i * x_hat_i  (elementwise).
 - ``embedding``  s = E[ids].  Ghost norm via the index-equality Gram
                  (never materializes the (V, p) per-sample gradient).
+- ``table``      s[b, c, k] = W[index[k], c]: a parameter table W (R, p)
+                 gathered by a static index that every sample shares and
+                 broadcast over the batch (BEiT's relative position bias:
+                 (2*14-1)^2 + 3 = 732 rows x 16 heads, gathered to
+                 (heads, 197, 197)); s: (B, p, T), T = positions gathered,
+                 D = R rows, no recorded activation (``GatherInfo`` holds
+                 the index).  Per-sample grad = the segment sum of gs_i into
+                 W's rows (R, p), always instantiated: the index-equality
+                 Gram would cost T^2 per sample.
 
 Stacked layers (``ScannedStack``) register the same tap names with a leading
 stack dimension; the engine folds stack dims into the layer-norm reduction
@@ -41,7 +50,7 @@ from typing import Any, Optional
 import jax
 import jax.numpy as jnp
 
-TapKind = str  # "matmul" | "scale" | "embedding"
+TapKind = str  # "matmul" | "scale" | "embedding" | "table" | "bias" | ...
 
 
 @dataclasses.dataclass(frozen=True)
@@ -53,6 +62,16 @@ class ConvInfo:
     padding: Any  # str or tuple of (lo, hi) pairs
     feature_group_count: int = 1
     rhs_dilation: tuple[int, ...] | None = None
+
+
+@dataclasses.dataclass(frozen=True, repr=False)
+class GatherInfo:
+    """Static row index of a ``table`` tap: position k reads row index[k]."""
+
+    index: tuple[int, ...]
+
+    def __repr__(self) -> str:
+        return f"GatherInfo(n={len(self.index)}, rows={max(self.index) + 1})"
 
 
 @dataclasses.dataclass(frozen=True)
@@ -71,6 +90,7 @@ class TapMeta:
     n_groups: int = 1  # group dim between B and T (MoE experts); norms sum over it
     stack_dims: tuple[int, ...] = ()  # leading dims added by ScannedStack
     conv: Optional[ConvInfo] = None
+    gather: Optional[GatherInfo] = None  # ``table`` taps
     batch_size: int = 0
     # fused taps compute their norm (and, in book-keeping mode, the residuals
     # the weighted-grad einsum needs) inside the backward pass (core/fused.py)
@@ -196,6 +216,7 @@ class Ctx:
         bias_path: Optional[str] = None,
         n_groups: int = 1,
         conv: Optional[ConvInfo] = None,
+        gather: Optional[GatherInfo] = None,
         late: bool = False,
     ) -> jax.Array:
         """Register pre-activation ``s`` with recorded input ``a``.
@@ -219,6 +240,7 @@ class Ctx:
             bias_path=self._join(bias_path) if bias_path else None,
             n_groups=n_groups,
             conv=conv,
+            gather=gather,
             batch_size=int(s.shape[0]),
             fused=fused,
             a_shape=tuple(int(d) for d in a.shape) if a is not None else None,

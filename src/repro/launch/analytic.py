@@ -48,6 +48,8 @@ def norm_flops(meta: dict[str, TapMeta], mode: str, decision_by: str = "space") 
                 total += reps * b * (2.0 * m.T * m.D * m.p)
         elif m.kind == "embedding":
             total += reps * b * (2.0 * m.T * m.T * (1 + m.p))
+        elif m.kind == "table":  # one-hot segment sum into the R rows
+            total += reps * b * 2.0 * m.T * m.D * m.p
         else:  # scale/bias/dw_conv: one elementwise pass
             total += reps * b * 2.0 * m.T * m.p
     return total

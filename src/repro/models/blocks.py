@@ -11,7 +11,7 @@ from repro.core.taps import Ctx
 from repro.nn.attention import Attention, make_kv_cache
 from repro.nn.mamba import MambaBlock
 from repro.nn.mlp import MLP, GatedMLP
-from repro.nn.module import LayerNorm, Module, Params, AxesTree, RMSNorm
+from repro.nn.module import LayerNorm, LayerScale, Module, Params, AxesTree, RMSNorm
 from repro.nn.moe import MoE
 from repro.nn.stack import SequentialBlocks
 from repro.nn.xlstm import MLSTMBlock, SLSTMBlock
@@ -19,17 +19,24 @@ from repro.nn.xlstm import MLSTMBlock, SLSTMBlock
 
 def _norm(cfg: ArchConfig, name: str, d: int, dtype, param_dtype):
     cls = RMSNorm if cfg.norm == "rmsnorm" else LayerNorm
-    return cls(name, d, dtype=dtype, param_dtype=param_dtype)
+    eps = {} if cfg.norm_eps is None else {"eps": cfg.norm_eps}
+    return cls(name, d, dtype=dtype, param_dtype=param_dtype, **eps)
 
 
 def _ffn(cfg: ArchConfig, name: str, d_ff: int, dtype, param_dtype):
     if cfg.act == "swiglu":
         return GatedMLP(name, cfg.d_model, d_ff, dtype=dtype, param_dtype=param_dtype)
-    return MLP(name, cfg.d_model, d_ff, dtype=dtype, param_dtype=param_dtype)
+    return MLP(name, cfg.d_model, d_ff, approximate=cfg.act != "gelu_erf",
+               dtype=dtype, param_dtype=param_dtype)
 
 
 class TransformerBlock(Module):
-    """Pre-norm attention + {MLP | MoE [+ parallel dense-residual MLP]}."""
+    """Pre-norm attention + {MLP | MoE [+ parallel dense-residual MLP]}.
+
+    ``cfg.layer_scale`` scales both residual branches by learned per-channel
+    gammas (``ls1``, ``ls2``); ``rel_pos_window`` gives the attention BEiT's
+    relative position bias over that patch grid (plus a CLS token).
+    """
 
     def __init__(
         self,
@@ -39,6 +46,7 @@ class TransformerBlock(Module):
         use_moe: bool = False,
         cross: bool = False,
         causal: bool = True,
+        rel_pos_window: Optional[tuple[int, int]] = None,
         dtype=jnp.float32,
         param_dtype=jnp.float32,
     ):
@@ -52,6 +60,9 @@ class TransformerBlock(Module):
             "attn", d, cfg.n_heads, cfg.n_kv,
             head_dim=cfg.head_dim,
             qkv_bias=cfg.qkv_bias,
+            k_bias=cfg.k_bias,
+            out_bias=cfg.out_bias,
+            rel_pos_window=rel_pos_window,
             use_rope=cfg.norm == "rmsnorm",  # LN families (whisper) use learned pos
             rope_theta=cfg.rope_theta,
             causal=causal,
@@ -78,11 +89,17 @@ class TransformerBlock(Module):
                 self.dense_mlp = _ffn(cfg, "dense_mlp", cfg.moe_dense_ff, dtype, param_dtype)
         else:
             self.mlp = _ffn(cfg, "mlp", cfg.d_ff, dtype, param_dtype)
+        self.scales = ()
+        if cfg.layer_scale:
+            self.scales = tuple(
+                LayerScale(n, d, init=cfg.layer_scale, dtype=dtype, param_dtype=param_dtype)
+                for n in ("ls1", "ls2"))
 
     def init(self, key: jax.Array) -> Params:
         ks = iter(jax.random.split(key, 8))
         p = {"n1": self.n1.init(next(ks)), "attn": self.attn.init(next(ks)),
              "n2": self.n2.init(next(ks))}
+        p.update({m.name: m.init(key) for m in self.scales})
         if self.cross:
             p["nx"] = self.nx.init(next(ks))
             p["xattn"] = self.xattn.init(next(ks))
@@ -96,6 +113,7 @@ class TransformerBlock(Module):
 
     def axes(self) -> AxesTree:
         a = {"n1": self.n1.axes(), "attn": self.attn.axes(), "n2": self.n2.axes()}
+        a.update({m.name: m.axes() for m in self.scales})
         if self.cross:
             a["nx"] = self.nx.axes()
             a["xattn"] = self.xattn.axes()
@@ -137,7 +155,7 @@ class TransformerBlock(Module):
             params["attn"], self.n1(params["n1"], x, ctx.scope("n1")),
             ctx.scope("attn"), positions=positions, cache=kv_cache,
         )
-        x = x + h
+        x = x + self._scaled(0, params, h, ctx)
         new_cache = {"kv": new_kv} if cache is not None else None
         if self.cross:
             xc = cache["xkv"] if cache is not None else None
@@ -155,7 +173,14 @@ class TransformerBlock(Module):
                 h = h + self.dense_mlp(params["dense_mlp"], h_in, ctx.scope("dense_mlp"))
         else:
             h = self.mlp(params["mlp"], h_in, ctx.scope("mlp"))
-        return x + h, new_cache
+        return x + self._scaled(1, params, h, ctx), new_cache
+
+    def _scaled(self, i: int, params: Params, h: jax.Array, ctx: Ctx) -> jax.Array:
+        """Residual branch ``i`` times its layer scale, where the block has one."""
+        if not self.scales:
+            return h
+        m = self.scales[i]
+        return m(params[m.name], h, ctx.scope(m.name))
 
 
 class MambaWrap(Module):

@@ -1,7 +1,9 @@
 """Grouped-query attention with RoPE, sliding windows, KV cache, cross-attn.
 
 Projections are ``Dense`` modules → each gets a DP tap; the attention math
-itself is parameter-free so the mixed-ghost machinery never needs to see it.
+itself is parameter-free so the mixed-ghost machinery never needs to see it,
+except BEiT's relative position bias: a (R, heads) table per layer, gathered
+by a static (T, T) index into an additive score bias, with a ``table`` tap.
 The score computation routes through the blocked flash implementation
 (``repro.kernels.flash_attention``) so (Sq, Skv) scores are never materialized.
 """
@@ -11,9 +13,10 @@ from typing import Optional
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 from jax import lax
 
-from repro.core.taps import Ctx
+from repro.core.taps import Ctx, GatherInfo
 from repro.kernels import dispatch
 from repro.kernels.flash_attention.ops import flash_attention
 from repro.nn.module import Dense, Module, Params, AxesTree
@@ -38,6 +41,24 @@ def make_kv_cache(
     }
 
 
+
+
+def relative_position_index(window: tuple[int, int]) -> np.ndarray:
+    """BEiT's (T, T) index into its relative position bias table, T = Wh*Ww + 1
+    with the CLS token first (timm's ``gen_relative_position_index``): patch
+    pairs read row (dh + Wh - 1) * (2 Ww - 1) + (dw + Ww - 1) of the offset
+    grid; the last three rows are CLS -> token, token -> CLS and CLS -> CLS."""
+    wh, ww = window
+    n_rel = (2 * wh - 1) * (2 * ww - 1) + 3
+    coords = np.stack(np.meshgrid(np.arange(wh), np.arange(ww), indexing="ij")).reshape(2, -1)
+    rel = (coords[:, :, None] - coords[:, None, :]).transpose(1, 2, 0)
+    rel = (rel[..., 0] + wh - 1) * (2 * ww - 1) + (rel[..., 1] + ww - 1)
+    index = np.zeros((wh * ww + 1,) * 2, np.int32)
+    index[1:, 1:] = rel
+    index[0, :] = n_rel - 3
+    index[:, 0] = n_rel - 2
+    index[0, 0] = n_rel - 1
+    return index
 
 
 def blocked_decode_attention(
@@ -101,7 +122,9 @@ class Attention(Module):
         *,
         head_dim: Optional[int] = None,
         qkv_bias: bool = False,
+        k_bias: bool = True,
         out_bias: bool = False,
+        rel_pos_window: Optional[tuple[int, int]] = None,
         use_rope: bool = True,
         rope_theta: float = 10000.0,
         causal: bool = True,
@@ -122,6 +145,11 @@ class Attention(Module):
         self.head_dim = head_dim or d_model // n_heads
         self.qkv_bias = qkv_bias
         self.out_bias = out_bias
+        self.rel_pos_window = rel_pos_window
+        if rel_pos_window is not None:
+            self.rel_index = relative_position_index(rel_pos_window).reshape(-1)
+            self.rel_rows = int(self.rel_index.max()) + 1
+            self.rel_gather = GatherInfo(tuple(int(i) for i in self.rel_index))
         self.use_rope = use_rope
         self.rope_theta = rope_theta
         self.causal = causal
@@ -141,7 +169,7 @@ class Attention(Module):
         )
         self.wk = Dense(
             f"{name}.k", d_model, n_kv * self.head_dim,
-            use_bias=qkv_bias, w_axes=("embed", "kv_heads"), **common,
+            use_bias=qkv_bias and k_bias, w_axes=("embed", "kv_heads"), **common,
         )
         self.wv = Dense(
             f"{name}.v", d_model, n_kv * self.head_dim,
@@ -155,20 +183,45 @@ class Attention(Module):
 
     def init(self, key: jax.Array) -> Params:
         ks = jax.random.split(key, 4)
-        return {
+        p = {
             "q": self.wq.init(ks[0]),
             "k": self.wk.init(ks[1]),
             "v": self.wv.init(ks[2]),
             "o": self.wo.init(ks[3]),
         }
+        if self.rel_pos_window is not None:
+            p["relative_position_bias_table"] = (0.02 * jax.random.truncated_normal(
+                jax.random.fold_in(key, 4), -2.0, 2.0, (self.rel_rows, self.n_heads)
+            )).astype(self.param_dtype)
+        return p
 
     def axes(self) -> AxesTree:
-        return {
+        a = {
             "q": self.wq.axes(),
             "k": self.wk.axes(),
             "v": self.wv.axes(),
             "o": self.wo.axes(),
         }
+        if self.rel_pos_window is not None:
+            a["relative_position_bias_table"] = (None, None)
+        return a
+
+    def _rel_pos_bias(self, params: Params, b: int, s: int, ctx: Ctx) -> jax.Array:
+        """(B, H, S, S) score bias: the layer's table gathered by the static
+        index, broadcast over the batch so that the tap sees each sample's
+        cotangent."""
+        if s * s != self.rel_index.size:
+            raise ValueError(f"relative position bias built for {self.rel_index.size} token "
+                             f"pairs, got {s} tokens")
+        table = params["relative_position_bias_table"].astype(self.dtype)
+        bias = jnp.broadcast_to(jnp.take(table.T, self.rel_index, axis=1),
+                                (b, self.n_heads, s * s))
+        if self.dp and ctx.collect:
+            bias = ctx.tap(
+                "rel_pos", bias, kind="table", T=s * s, D=self.rel_rows, p=self.n_heads,
+                param_path="relative_position_bias_table", gather=self.rel_gather,
+            )
+        return bias.reshape(b, self.n_heads, s, s)
 
     def __call__(
         self,
@@ -213,9 +266,10 @@ class Attention(Module):
             k = apply_rope(k, positions, self.rope_theta)
 
         if cache is None:
+            bias = None if self.rel_pos_window is None else self._rel_pos_bias(params, b, s, ctx)
             out = flash_attention(
                 q, k, v, causal=self.causal, window=self.window,
-                block_q=self.block_q, block_kv=self.block_kv,
+                block_q=self.block_q, block_kv=self.block_kv, bias=bias,
             )
             new_cache = None
         else:

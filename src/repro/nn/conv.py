@@ -30,7 +30,9 @@ def unfold2d(x: jax.Array, info: ConvInfo) -> jax.Array:
     channels.  This is the conv weight's own (kh, kw, d, p) order, so a
     (kh*kw*d, p) gradient reshapes to the weight with no transpose; and the
     channels stay minor, so on a TPU the patches leave one fusion in the
-    row-major layout the Pallas ghost norm reads.
+    row-major layout the Pallas ghost norm reads.  Where the patches tile the
+    input (stride = kernel, no padding, no dilation: a ViT's patch
+    embedding) the same order is one reshape and transpose.
     """
     kh, kw = info.kernel
     sh, sw = info.strides
@@ -41,6 +43,19 @@ def unfold2d(x: jax.Array, info: ConvInfo) -> jax.Array:
         pads = lax.padtype_to_pads(
             (h, w), ((kh - 1) * dh + 1, (kw - 1) * dw + 1), (sh, sw), pads
         )
+    if (sh, sw) == (kh, kw) and (dh, dw) == (1, 1) and all(p == 0 for lh in pads for p in lh):
+        ho, wo = h // kh, w // kw
+        tiles = x[:, : ho * kh, : wo * kw].reshape(b, ho, kh, wo, kw, d)
+        return tiles.transpose(0, 1, 3, 2, 4, 5).reshape(b, ho * wo, kh * kw * d)
+    return _unfold2d_slices(x, info, pads)
+
+
+def _unfold2d_slices(x: jax.Array, info: ConvInfo, pads) -> jax.Array:
+    """``unfold2d`` as one strided slice per kernel offset, any conv."""
+    kh, kw = info.kernel
+    sh, sw = info.strides
+    dh, dw = info.rhs_dilation or (1, 1)
+    b, h, w, d = x.shape
     (top, bottom), (left, right) = pads
     xp = lax.pad(x, jnp.zeros((), x.dtype), ((0, 0, 0), (top, bottom, 0), (left, right, 0),
                                              (0, 0, 0)))

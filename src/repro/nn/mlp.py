@@ -49,7 +49,8 @@ class GatedMLP(Module):
 
 
 class MLP(Module):
-    """Plain transformer FFN with GELU (whisper, ViT, phi-style)."""
+    """Plain transformer FFN with GELU (whisper, ViT, phi-style); the tanh
+    approximation unless ``approximate=False`` (BEiT's exact erf GELU)."""
 
     def __init__(
         self,
@@ -58,11 +59,13 @@ class MLP(Module):
         d_ff: int,
         *,
         use_bias: bool = True,
+        approximate: bool = True,
         dtype=jnp.float32,
         param_dtype=jnp.float32,
         dp: bool = True,
     ):
         self.name = name
+        self.approximate = approximate
         common = dict(dtype=dtype, param_dtype=param_dtype, dp=dp, use_bias=use_bias)
         self.wi = Dense(f"{name}.wi", d_model, d_ff, w_axes=("embed", "mlp"), **common)
         self.wo = Dense(f"{name}.wo", d_ff, d_model, w_axes=("mlp", "embed"), **common)
@@ -75,5 +78,5 @@ class MLP(Module):
         return {"wi": self.wi.axes(), "wo": self.wo.axes()}
 
     def __call__(self, params: Params, x: jax.Array, ctx: Ctx) -> jax.Array:
-        h = jax.nn.gelu(self.wi(params["wi"], x, ctx.scope("wi")))
+        h = jax.nn.gelu(self.wi(params["wi"], x, ctx.scope("wi")), approximate=self.approximate)
         return self.wo(params["wo"], h, ctx.scope("wo"))

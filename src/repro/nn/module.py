@@ -258,6 +258,39 @@ class LayerNorm(Module):
         return s
 
 
+class LayerScale(Module):
+    """x * gamma, a per-channel scale on a residual branch (CaiT, BEiT),
+    with a DP "scale" tap; gamma starts at ``init``."""
+
+    def __init__(self, name: str, d: int, *, init: float, dtype=jnp.float32,
+                 param_dtype=jnp.float32, dp: bool = True):
+        self.name = name
+        self.d = d
+        self.init_value = init
+        self.dtype = dtype
+        self.param_dtype = param_dtype
+        self.dp = dp
+
+    def init(self, key: jax.Array) -> Params:
+        del key
+        return {"g": jnp.full((self.d,), self.init_value, self.param_dtype)}
+
+    def axes(self) -> AxesTree:
+        return {"g": (None,)}
+
+    def __call__(self, params: Params, x: jax.Array, ctx: Ctx) -> jax.Array:
+        x = x.astype(self.dtype)
+        s = x * params["g"].astype(self.dtype)
+        if self.dp and ctx.collect:
+            batch = x.shape[0]
+            t = int(math.prod(x.shape[1:-1])) if x.ndim > 2 else 1
+            s = ctx.tap(
+                "out", s, kind="scale", a=x.reshape(batch, t, self.d),
+                T=t, D=self.d, p=self.d, param_path="g",
+            )
+        return s
+
+
 class GroupNorm(Module):
     """GroupNorm (the paper swaps BatchNorm for GroupNorm — BN is not DP-safe
     because batch statistics mix samples)."""

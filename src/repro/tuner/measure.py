@@ -31,8 +31,8 @@ norm for embeddings) and the branch timings are then taken *under the
 winning impls*, which are recorded in the plan's v5 ``kernels`` map.  Off
 TPU there is exactly one production impl (xla), recorded without timing.
 
-Only matmul taps get branch timings.  Embedding / scale / bias / dw_conv
-taps have a single viable branch (decision.decide's forced cases) and are
+Only matmul taps get branch timings.  Embedding / scale / bias / dw_conv /
+table taps have a single viable branch (decision.decide's forced cases) and are
 never overridden — embeddings still get a kernel-impl measurement.
 """
 from __future__ import annotations
@@ -109,7 +109,7 @@ def _tap_rows(meta: TapMeta, max_rows: Optional[int]) -> int:
 
 
 # dispatch ops with a measurable impl choice, per tap kind; scale / bias /
-# dw_conv taps bank tiny per-sample grads and keep the dispatch default
+# dw_conv / table taps bank tiny per-sample grads and keep the dispatch default
 KERNEL_OPS_BY_KIND = {
     "matmul": ("ghost_norm", "psg_contract"),
     "embedding": ("embedding_ghost_norm",),

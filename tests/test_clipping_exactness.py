@@ -172,6 +172,25 @@ def test_unfold2d_is_offset_major(kernel, strides, padding, dilation):
     assert jnp.allclose(via, conv.reshape(via.shape), rtol=1e-5, atol=1e-5)
 
 
+@pytest.mark.parametrize("shape,kernel,padding", [
+    ((2, 32, 32, 3), (8, 8), "VALID"),  # a ViT patch embedding
+    ((2, 9, 11, 5), (4, 4), "VALID"),  # rows and columns left over
+    ((2, 8, 12, 5), (4, 2), "SAME"),  # SAME that pads nothing
+    ((1, 6, 6, 2), (3, 3), ((0, 0), (0, 0))),
+])
+def test_unfold2d_patchify_equals_slicing(shape, kernel, padding):
+    """Where stride = kernel with no padding or dilation, unfold2d reshapes
+    and transposes: the same patches, in the same order, as the slices."""
+    from repro.core.taps import ConvInfo
+    from repro.nn.conv import _unfold2d_slices, unfold2d
+
+    info = ConvInfo(kernel, kernel, padding)
+    x = jax.random.normal(jax.random.PRNGKey(7), shape)
+    pads = padding if not isinstance(padding, str) else jax.lax.padtype_to_pads(
+        shape[1:3], kernel, kernel, padding)
+    assert jnp.array_equal(unfold2d(x, info), _unfold2d_slices(x, info, pads))
+
+
 class _StackModel(Module):
     def __init__(self):
         d = 16

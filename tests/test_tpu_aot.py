@@ -170,3 +170,28 @@ def test_kernel_compiles_split_over_four_chips(case, four_chips, on_tpu):
     with use_reshard_rules(four_chips):
         compiled = jax.jit(fn).lower(*args).compile()
     assert "tpu_custom_call" in compiled.as_text()
+
+
+def test_beit_large_clipped_grad_compiles_for_one_chip(one_chip, on_tpu):
+    """BEiT-large/16 at 224 px, batch 128, under mixed ghost clipping: the
+    whole clipped-gradient program for one v5e, with the ghost kernel in it
+    (every matmul tap, T = 197 and 196) and its temporaries under 10 GB
+    (5.03 GB when written), so that a memory regression shows without a chip."""
+    from repro.configs.paper_native import BEIT_LARGE
+    from repro.core.engine import PrivacyEngine
+    from repro.models.vit import BEiT
+
+    model = BEiT(BEIT_LARGE, image_size=224, patch=16, n_classes=10)
+    params = jax.tree_util.tree_map(
+        lambda s: jax.ShapeDtypeStruct(s.shape, s.dtype, sharding=one_chip),
+        jax.eval_shape(model.init, jax.random.PRNGKey(0)))
+    batch = {"image": jax.ShapeDtypeStruct((128, 224, 224, 3), F32, sharding=one_chip),
+             "label": jax.ShapeDtypeStruct((128,), I32, sharding=one_chip),
+             "mask": jax.ShapeDtypeStruct((128,), F32, sharding=one_chip)}
+    engine = PrivacyEngine(loss_with_ctx=model.loss_with_ctx, batch_size=128, sample_size=50000,
+                           steps=2000, max_grad_norm=1.0, noise_multiplier=1.0,
+                           mode="mixed_ghost")
+    compiled = jax.jit(engine.clipped_grad_fn()).lower(params, batch).compile()
+    assert "ghost_norm_sq_pallas" in compiled.as_text()
+    assert "tpu_custom_call" in compiled.as_text()
+    assert compiled.memory_analysis().temp_size_in_bytes < 10e9
