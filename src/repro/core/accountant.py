@@ -6,12 +6,20 @@ improved RDP->(eps, delta) conversion used by Opacus/TF-Privacy.  Pure numpy —
 this runs on the host, never inside jit.  Each ``RDPAccountant.step`` is a
 ``dp.accountant`` span on the profiler's host timeline.
 
+A per-step curve depends only on ``(q, sigma)`` under the accountant's fixed
+``alphas``, so ``RDPAccountant`` computes it once per distinct key and keeps
+it (read-only, at most ``CURVE_CACHE_SIZE`` keys); a step then costs one
+vector addition.  ``curve_evals`` counts the curves computed and
+``steps_composed`` the steps added; ``1 - curve_evals / steps_composed`` is
+the cache's hit share.
+
 The paper's engine (Appendix E) exposes ``target_epsilon`` -> ``sigma``; we
 recover sigma by bisection on the accountant.
 """
 from __future__ import annotations
 
 import math
+from collections import OrderedDict
 from typing import Sequence
 
 import numpy as np
@@ -20,6 +28,8 @@ from scipy import special
 
 DEFAULT_ALPHAS = tuple(range(2, 64)) + tuple(range(64, 513, 8))
 ACCOUNTANT_SPAN = "dp.accountant"
+# a run composes the gradient mechanism and at most one policy release
+CURVE_CACHE_SIZE = 8
 
 
 def rdp_gaussian(sigma: float, alphas: Sequence[int]) -> np.ndarray:
@@ -74,6 +84,25 @@ class RDPAccountant:
     def __init__(self, alphas: Sequence[int] = DEFAULT_ALPHAS):
         self.alphas = tuple(alphas)
         self._rdp = np.zeros(len(self.alphas))
+        self._curves: OrderedDict[tuple[float, float], np.ndarray] = OrderedDict()
+        self.curve_evals = 0
+        self.steps_composed = 0
+
+    def curve(self, q: float, sigma: float) -> np.ndarray:
+        """The read-only per-step RDP curve at ``(q, sigma)``, computed once
+        per key and kept for the ``CURVE_CACHE_SIZE`` keys used last."""
+        key = (float(q), float(sigma))
+        r = self._curves.get(key)
+        if r is None:
+            r = rdp_subsampled_gaussian(*key, self.alphas)
+            r.setflags(write=False)
+            self.curve_evals += 1
+            if len(self._curves) >= CURVE_CACHE_SIZE:
+                self._curves.popitem(last=False)
+            self._curves[key] = r
+        else:
+            self._curves.move_to_end(key)
+        return r
 
     def step(self, *, q: float, sigma: float, steps: int = 1) -> None:
         # compose one step at a time, not as `steps * rdp`: float addition is
@@ -82,9 +111,10 @@ class RDPAccountant:
         # epsilon trajectory of the uninterrupted run) depends on replaying
         # the same additions in the same order
         with TraceAnnotation(ACCOUNTANT_SPAN):
-            r = rdp_subsampled_gaussian(q, sigma, self.alphas)
+            r = self.curve(q, sigma)
             for _ in range(steps):
                 self._rdp = self._rdp + r
+            self.steps_composed += steps
 
     def get_epsilon(self, delta: float) -> float:
         eps, _ = eps_from_rdp(self._rdp, self.alphas, delta)
