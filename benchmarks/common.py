@@ -7,7 +7,7 @@ from typing import Any, Callable
 import jax
 import jax.numpy as jnp
 
-from repro.core.clipping import ClipConfig, dp_value_and_clipped_grad
+from repro.core.clipping import MODES, ClipConfig, dp_value_and_clipped_grad
 from repro.core.taps import Ctx
 from repro.data.synthetic import synthetic_vision_batch
 from repro.models.cnn import VGG
@@ -86,4 +86,4 @@ def clipping_step_fn(model, mode: str, clip_norm: float = 1.0):
     )
 
 
-MODES_BENCH = ["non_private", "vmap", "ghost", "fastgradclip", "mixed_ghost", "bk_mixed"]
+MODES_BENCH = list(MODES)

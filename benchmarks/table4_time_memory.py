@@ -63,7 +63,7 @@ def vgg11_memory(batch: int = 128) -> list[tuple[str, float, str]]:
     rows = []
     from repro.core.clipping import ClipConfig, dp_value_and_clipped_grad
 
-    for mode in ["non_private", "vmap", "ghost", "mixed_ghost"]:
+    for mode in MODES_BENCH:
         fn = dp_value_and_clipped_grad(model.loss_with_ctx, ClipConfig(mode=mode))
         mem = compiled_memory_bytes(fn, *specs)
         rows.append((f"table6_vgg11_b{batch}_{mode}", 0.0, f"mem_gb={mem/1e9:.2f}"))

@@ -3,8 +3,8 @@
 ``audit_loss_fn`` is the core entry — it takes the same
 ``loss_with_ctx(params, batch, ctx)`` contract the clipping engines consume
 (src/repro/core/clipping.py), traces the *explicit-tap* formulation
-(zero taps added, activations recorded — the reference engine the fused
-probes are tested against), and runs:
+(zero taps added, activations recorded — the channel the fused engine
+keeps for late taps), and runs:
 
 1. the batch-axis taint pass (``repro.analysis.taint``) over the jaxpr,
    checking every tap-add site, every recorded activation, and the

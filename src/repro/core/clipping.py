@@ -23,10 +23,11 @@ argument and thread the updated state through the train step
 
 Modes
 -----
-- ``vmap``        Opacus analogue: materialize per-sample grads via
-                  vmap(grad), clip, sum.  O(B x |params|) memory.
-- ``ghost``       ghost norm everywhere + second backward pass.
-- ``fastgradclip``  instantiation norms + second backward pass.
+- ``non_private`` no clipping (C_i = 1); the baseline every overhead claim is
+                  measured against.
+- ``vmap``        Opacus analogue and the exactness oracle: materialize
+                  per-sample grads via vmap(grad), clip, sum.  O(B x |params|)
+                  memory.
 - ``mixed_ghost`` the paper's Algorithm 1: Eq-(4.1) layerwise decision
                   between ghost norm and instantiation + second backward.
 - ``bk_mixed``    beyond-paper: book-keeping (arXiv:2210.00038) — the fused
@@ -34,14 +35,12 @@ Modes
                   the single backward pass and the gradient stage is a direct
                   einsum against the clip factors.  No second backward; DP
                   cost ~= non-private cost.
-- ``*_taps``      thin reference executors on the explicit-tap engine
-                  (zero taps + activation dict); the exactness oracle for the
-                  fused engine and the fallback for experimentation.
-- ``non_private`` no clipping (C_i = 1); the baseline every overhead claim is
-                  measured against.
 
-All modes produce bit-identical clipped gradients (tested): the paper's claim
-that the implementation "does not affect the mathematics".
+The clipped mechanism is the same in every mode (tested against ``vmap``):
+the paper's claim that the implementation "does not affect the mathematics".
+The paper's ghost-only and instantiate-only rivals are ``mixed_ghost``
+under a ``ClipConfig(plan=...)`` whose branch map forces every matmul tap to
+``ghost`` or to ``instantiate``; a plan moves cost, never results.
 
 Mode selection guide
 --------------------
@@ -61,8 +60,7 @@ Which engine wins depends on {memory budget, architecture, device}:
   book-keeping per tap on the device and writes a ClipPlan whose
   ``recommended_mode()`` settles the question with measurements; ``launch.train
   --tune --mode auto`` adopts it end to end.
-- **Debugging / cross-checking**: ``vmap`` (the oracle, tiny models only) and
-  the ``*_taps`` reference executors.
+- **Debugging / cross-checking**: ``vmap`` (the oracle, tiny models only).
 
 Flow for the fused second-backward family (1 forward + 2 backward, Fig. 1
 right)::
@@ -92,13 +90,7 @@ from repro.utils.tree import flatten_dict, unflatten_dict
 
 LossFn = Callable[..., jax.Array]  # (params, batch, ctx) -> (B,) losses
 
-# fused engine: ghost | fastgradclip | mixed_ghost | bk_mixed (probe-based)
-# explicit-tap engine: *_taps reference variants
-MODES = (
-    "vmap", "ghost", "fastgradclip", "mixed_ghost", "bk_mixed",
-    "ghost_taps", "fastgradclip_taps", "mixed_ghost_taps", "bk_mixed_taps",
-    "non_private",
-)
+MODES = ("non_private", "vmap", "mixed_ghost", "bk_mixed")
 
 # stage scopes: the first pass (forward, first backward, per-sample norms,
 # clip factors) and the gradient stage (second backward or bank einsums)
@@ -111,9 +103,6 @@ class ClipConfig:
     mode: str = "mixed_ghost"
     clip_norm: float = 1.0
     clip_fn: str = "abadi"
-    decision_by: str = "space"  # Eq 4.1 (space) or Remark 4.1 (time)
-    ghost_block: int = 512
-    inst_block_d: int = 8192
     # taps whose params are frozen (no clipping/noise/coverage requirement)
     frozen_prefixes: tuple[str, ...] = ()
     # measured-cost branch plan (repro.tuner.ClipPlan, duck-typed to keep
@@ -126,6 +115,10 @@ class ClipConfig:
     # behavior.  Stateful policies (quantile R) receive their state as the
     # executor's third argument.
     policy: Optional[Any] = None
+
+    def __post_init__(self):
+        if self.mode not in MODES:
+            raise ValueError(f"unknown clipping mode {self.mode!r}; have {MODES}")
 
 
 def _plan_overrides(
@@ -221,7 +214,7 @@ def _batch_mask(batch: Any) -> Optional[jax.Array]:
 def _assemble_bk_grads(
     meta: dict[str, TapMeta], params: Any, ws_fn: Callable
 ) -> Any:
-    """Shared book-keeping gradient assembly (fused and reference engines).
+    """Book-keeping gradient assembly from per-tap weighted gradients.
 
     ``ws_fn(name, m, param_shape)`` yields one tap's {path: weighted grad};
     uncovered leaves (frozen params) are zero-filled and everything is cast
@@ -252,8 +245,8 @@ def _grouped_second_backward(st: "_NormState", c: Any, params: Any) -> Any:
     The pullback cotangent is per-*sample* — one scalar weight per loss —
     so a factor that differs per layer group cannot ride a single second
     backward.  Run one pullback per group and keep each group's own leaves:
-    correct for any G, at G x the second-backward cost.  The book-keeping
-    engines do this for free (per-tap einsums); prefer them when G is large.
+    correct for any G, at G x the second-backward cost.  Book-keeping does
+    this for free (per-tap einsums); prefer it when G is large.
     """
     out: dict[str, jax.Array] = {}
     for gi in range(len(c.groups)):
@@ -272,8 +265,8 @@ class _NormState:
     norms2: jax.Array
     pull: Optional[Callable] = None  # vjp pullback (second-backward modes)
     banks: Optional[dict] = None  # per-tap probe cotangents (fused engine)
-    acts: Optional[dict] = None  # explicit activations (taps engine / late)
-    gs: Optional[dict] = None  # explicit tap cotangents
+    acts: Optional[dict] = None  # explicit activations (late taps)
+    gs: Optional[dict] = None  # explicit tap cotangents (late taps)
     meta: Optional[dict] = None
     # per-tap kernel-impl choices from the plan ({} = dispatch defaults)
     kernels: Optional[dict] = None
@@ -451,9 +444,9 @@ def _fold_bank_norm(n: jax.Array, b: int) -> jax.Array:
 class FusedExecutor(ClipExecutor):
     """Probe engine: norms (and bk banks) computed inside the backward pass.
 
-    Covers ghost / fastgradclip / mixed_ghost (gradient stage = second
-    backward over the shared pullback) and bk_mixed (gradient stage = bank
-    einsums; the single backward is all the backpropagation there is).
+    Covers mixed_ghost (gradient stage = second backward over the shared
+    pullback) and bk_mixed (gradient stage = bank einsums; the single
+    backward is all the backpropagation there is).
     Taps registered with ``late=True`` (recurrent weights whose activation
     only exists after the time scan) fall back to the explicit-tap channel
     within the same pipeline.
@@ -461,10 +454,7 @@ class FusedExecutor(ClipExecutor):
 
     def __init__(self, loss_with_ctx: LossFn, cfg: ClipConfig):
         super().__init__(loss_with_ctx, cfg)
-        self.base_runtime = ClipRuntime(
-            mode=cfg.mode, decision_by=cfg.decision_by,
-            ghost_block=cfg.ghost_block, inst_block_d=cfg.inst_block_d,
-        )
+        self.base_runtime = ClipRuntime(mode=cfg.mode)
 
     @property
     def is_bk(self) -> bool:
@@ -485,10 +475,7 @@ class FusedExecutor(ClipExecutor):
         )
         zs0 = {
             name: fused_mod.make_bank_zeros(
-                fused_mod.bank_struct(
-                    m, mode=cfg.mode, decision_by=cfg.decision_by,
-                    override=overrides.get(name),
-                )
+                fused_mod.bank_struct(m, mode=cfg.mode, override=overrides.get(name))
             )
             for name, m in meta.items() if m.fused
         }
@@ -513,11 +500,8 @@ class FusedExecutor(ClipExecutor):
                 n = _fold_bank_norm(banks[name]["n"], b)
             else:
                 n = ghost.tap_norm_sq(
-                    m, acts.get(name), gs_late[name],
-                    mode=cfg.mode, decision_by=cfg.decision_by,
-                    ghost_block=cfg.ghost_block, inst_block_d=cfg.inst_block_d,
-                    override=overrides.get(name),
-                    kernels=kernel_map.get(name),
+                    m, acts.get(name), gs_late[name], mode=cfg.mode,
+                    override=overrides.get(name), kernels=kernel_map.get(name),
                 )
             norms2 = norms2 + n
             if path_norms2 is not None:
@@ -557,85 +541,11 @@ class FusedExecutor(ClipExecutor):
         return _assemble_bk_grads(st.meta, params, ws_fn)
 
 
-class TapsExecutor(ClipExecutor):
-    """Reference explicit-tap engine (``*_taps`` modes).
-
-    Materializes zero taps and an activation dict — the memory-hungry but
-    transparent formulation the fused engine is tested against.
-    """
-
-    def __init__(self, loss_with_ctx: LossFn, cfg: ClipConfig):
-        super().__init__(loss_with_ctx, cfg)
-        self.branch_mode = cfg.mode.replace("_taps", "")
-
-    def _norm_state(self, params, batch) -> _NormState:
-        cfg = self.cfg
-        meta = discover_meta(self.loss, params, batch)
-        overrides = _plan_overrides(cfg.plan, meta, self.branch_mode)
-        kernel_map = _plan_kernels(cfg.plan, meta)
-        taps0 = make_zero_taps(meta)
-
-        def f(p, taps):
-            ctx = Ctx(taps=taps, meta={})
-            losses = self.loss(p, batch, ctx)
-            return losses, ctx.acts
-
-        losses, pull, acts = jax.vjp(f, params, taps0, has_aux=True)
-        b = losses.shape[0]
-        ones = jnp.ones_like(losses)
-        _, gs = pull(ones)  # first backward; unused param grads are DCE'd
-
-        if self.grouped:
-            self._validate_groups(meta)
-        norms2 = jnp.zeros((b,), jnp.float32)
-        path_norms2: Optional[dict[str, jax.Array]] = {} if self.grouped else None
-        for name, m in meta.items():
-            n = ghost.tap_norm_sq(
-                m, acts.get(name), gs[name],
-                mode=self.branch_mode, decision_by=cfg.decision_by,
-                ghost_block=cfg.ghost_block, inst_block_d=cfg.inst_block_d,
-                override=overrides.get(name),
-                kernels=kernel_map.get(name),
-            )
-            norms2 = norms2 + n
-            if path_norms2 is not None:
-                path_norms2[m.param_path] = (
-                    path_norms2[m.param_path] + n
-                    if m.param_path in path_norms2 else n
-                )
-        return _NormState(
-            losses=losses, norms2=norms2, pull=pull, acts=acts, gs=gs,
-            meta=meta, path_norms2=path_norms2, kernels=kernel_map,
-        )
-
-    def _weighted_grads(self, st, c, params):
-        grouped = hasattr(c, "for_path")
-        if self.branch_mode != "bk_mixed":
-            if grouped:
-                return _grouped_second_backward(st, c, params)
-            clipped, _ = st.pull(c.astype(st.losses.dtype))  # second backward
-            return clipped
-        return _assemble_bk_grads(
-            st.meta, params,
-            lambda name, m, shape: ghost.tap_weighted_grads(
-                m, st.acts.get(name), st.gs[name],
-                c.for_path(m.param_path) if grouped else c, shape,
-                kernels=(st.kernels or {}).get(name),
-            ),
-        )
-
-
 _EXECUTORS = {
     "non_private": NonPrivateExecutor,
     "vmap": VmapExecutor,
-    "ghost": FusedExecutor,
-    "fastgradclip": FusedExecutor,
     "mixed_ghost": FusedExecutor,
     "bk_mixed": FusedExecutor,
-    "ghost_taps": TapsExecutor,
-    "fastgradclip_taps": TapsExecutor,
-    "mixed_ghost_taps": TapsExecutor,
-    "bk_mixed_taps": TapsExecutor,
 }
 
 
@@ -652,9 +562,7 @@ def dp_value_and_clipped_grad(
     ``policy_state`` feeds a stateful ClipPolicy (``cfg.policy``); the
     policy's *update* runs outside this function (once per logical batch,
     see ``launch.steps``), so the executor stays a pure clipping map.
+    An unknown ``cfg.mode`` raises ``ValueError`` when ``ClipConfig`` is
+    built.
     """
-    try:
-        executor_cls = _EXECUTORS[cfg.mode]
-    except KeyError:
-        raise ValueError(f"unknown clipping mode {cfg.mode!r}; have {MODES}") from None
-    return executor_cls(loss_with_ctx, cfg)
+    return _EXECUTORS[cfg.mode](loss_with_ctx, cfg)

@@ -88,45 +88,51 @@ def decide(
     per sample against its R*p rows); embeddings always use the index-equality
     ghost norm (instantiating a (V, p) gradient per sample is never viable).
 
-    ``override`` is a measured-cost branch from a ``repro.tuner`` ClipPlan:
-    it wins over the analytic Eq-(4.1) rule (both branches compute the same
-    per-sample norm, so the choice is pure performance), but never over a
-    forced kind, and never over the pure reference modes ('ghost',
-    'fastgradclip'), whose whole point is a fixed branch everywhere.
+    ``mode`` is ``mixed_ghost`` (Eq. 4.1) or ``bk_mixed`` (the bank-size
+    rule).  ``override`` is a measured-cost branch from a ``repro.tuner``
+    ClipPlan: it wins over the analytic rule (both branches compute the
+    same per-sample norm, so the choice is pure performance), but never
+    over a forced kind.  A plan that overrides every matmul tap forces one
+    branch everywhere (the paper's ghost-only and instantiate-only rivals).
     """
     if meta.kind == "embedding":
         return "ghost"
     if meta.kind != "matmul":
         return "instantiate"
-    if mode in ("ghost",):
-        return "ghost"
-    if mode in ("instantiate", "fastgradclip"):
-        return "instantiate"
-    if mode in ("mixed_ghost", "bk_mixed"):
-        if override is not None:
-            if override not in ("ghost", "instantiate"):
-                raise ValueError(f"invalid branch override {override!r}")
-            return override
-        if mode == "bk_mixed":
-            # book-keeping banks residuals instead of paying a second
-            # backward; its branch economics are bank-size driven
-            a_elems = None
-            if meta.a_shape is not None:
-                rows = max(meta.n_stack * meta.batch_size, 1)
-                a_elems = math.prod(meta.a_shape) // rows
-            return "ghost" if bk_bank_prefers_ghost(
-                meta.T, meta.D, meta.p,
-                groups=max(meta.n_groups, 1), a_elems=a_elems,
-            ) else "instantiate"
-        return "ghost" if ghost_is_cheaper(meta.T, meta.D, meta.p, by=by) else "instantiate"
-    raise ValueError(f"unknown clipping mode {mode!r}")
+    if mode not in ("mixed_ghost", "bk_mixed"):
+        raise ValueError(f"unknown clipping mode {mode!r}")
+    if override is not None:
+        if override not in ("ghost", "instantiate"):
+            raise ValueError(f"invalid branch override {override!r}")
+        return override
+    if mode == "bk_mixed":
+        # book-keeping banks residuals instead of paying a second
+        # backward; its branch economics are bank-size driven
+        a_elems = None
+        if meta.a_shape is not None:
+            rows = max(meta.n_stack * meta.batch_size, 1)
+            a_elems = math.prod(meta.a_shape) // rows
+        return "ghost" if bk_bank_prefers_ghost(
+            meta.T, meta.D, meta.p,
+            groups=max(meta.n_groups, 1), a_elems=a_elems,
+        ) else "instantiate"
+    return "ghost" if ghost_is_cheaper(meta.T, meta.D, meta.p, by=by) else "instantiate"
+
+
+# Table 1's rivals with one branch everywhere
+_RIVAL_BRANCH = {"ghost": "ghost", "fastgradclip": "instantiate"}
 
 
 def algorithm_cost(
     metas: dict[str, TapMeta], mode: str, *, by: str = "space"
 ) -> dict[str, float]:
     """Table 2: total per-iteration time/space of a clipping algorithm,
-    summing matmul taps (the paper's analysis covers linear/conv layers)."""
+    summing matmul taps (the paper's analysis covers linear/conv layers).
+
+    ``mode`` is a clipping mode or one of Table 1's rivals: ``opacus``
+    (per-sample gradients), ``ghost`` (ghost norm everywhere) and
+    ``fastgradclip`` (instantiation everywhere).  The rivals are cost rows
+    only; the engine runs them as ``mixed_ghost`` under a forcing plan."""
     time = 0.0
     space = 0.0
     peak_clip_space = 0.0
@@ -147,7 +153,7 @@ def algorithm_cost(
             # Opacus holds per-sample grads of ALL layers simultaneously
             space += reps * (bp.space + gi.space)
             continue
-        branch = decide(m, mode=mode if mode != "fastgradclip" else "instantiate", by=by)
+        branch = _RIVAL_BRANCH.get(mode) or decide(m, mode=mode, by=by)
         mod = ghost_norm(B, T, D, p) if branch == "ghost" else grad_instantiation(B, T, D, p)
         if mode == "bk_mixed":
             # no second backward; instead every tap banks residuals until the

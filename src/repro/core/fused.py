@@ -18,8 +18,8 @@ side-channel payload from its residual ``a`` and the incoming cotangent ``g``
                da = 0                      (a's real grad flows via the matmul)
                dz = bank                   <- the hijacked side channel
 
-For the second-backward modes (ghost / fastgradclip / mixed_ghost) the bank
-is just ``{"n": (B,)}`` — the per-sample squared-norm contribution (ghost or
+For the second-backward mode (``mixed_ghost``) the bank is just
+``{"n": (B,)}`` — the per-sample squared-norm contribution (ghost or
 instantiated, per the Eq. 4.1 decision).  ``vjp(..., zs)`` then yields every
 layer's norms as (B,)-sized cotangents — inside ``lax.scan`` they stack to
 (L, B) — while g itself never leaves the backward scan.  Under the second
@@ -54,9 +54,6 @@ class ProbeSpec:
 
     meta: "taps_mod.TapMeta"
     branch_mode: str  # clipping mode used by decide()
-    decision_by: str = "space"
-    ghost_block: int = 512
-    inst_block_d: int = 8192
     override: Optional[str] = None  # tuner ClipPlan branch, wins over decide()
     # measured (op, impl) kernel choices for this tap (repro.kernels.dispatch)
     kernels: tuple[tuple[str, str], ...] = ()
@@ -66,7 +63,6 @@ def bank_struct(
     meta: "taps_mod.TapMeta",
     *,
     mode: str,
-    decision_by: str = "space",
     override: Optional[str] = None,
 ) -> dict[str, jax.ShapeDtypeStruct]:
     """Shapes/dtypes of one tap's bank (stack dims follow ``meta``).
@@ -84,7 +80,7 @@ def bank_struct(
 
     banks_book = False
     if meta.kind == "matmul":
-        branch = decide(meta, mode="bk_mixed", by=decision_by, override=override)
+        branch = decide(meta, mode="bk_mixed", override=override)
         if branch == "instantiate":
             out["psg"] = jax.ShapeDtypeStruct(
                 sd + (b,) + ghost_mod.psg_param_shape(meta), f32
@@ -131,9 +127,6 @@ def make_probe(spec: ProbeSpec):
             a,
             g,
             mode=spec.branch_mode,
-            decision_by=spec.decision_by,
-            ghost_block=spec.ghost_block,
-            inst_block_d=spec.inst_block_d,
             override=spec.override,
             kernels=dict(spec.kernels) if spec.kernels else None,
         )

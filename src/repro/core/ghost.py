@@ -12,8 +12,8 @@ device trace can tell the per-tap norm ops, pads and kernel calls apart.
 
 Three call sites:
 - ``tap_norm_sq``        per-sample norm^2 from explicit (a, g) pairs; used
-                         by the reference ``*_taps`` engine and the fused
-                         probes of the second-backward modes.
+                         by the fused probes of ``mixed_ghost`` and by late
+                         taps, whose activation only exists post-scan.
 - ``tap_bank``           runs INSIDE the fused probe's backward rule: returns
                          the side-channel payload for one tap — always the
                          per-sample norm^2 ``n``, plus (book-keeping mode) the
@@ -22,8 +22,8 @@ Three call sites:
                          ``(a, g)`` book for ghost-banked taps).
 - ``bank_weighted_grads``  the fused gradient stage: ``sum_i C_i g_i`` from a
                          tap's bank once the clip factors are known.
-- ``tap_weighted_grads``   same, from explicit (a, g) (reference engine and
-                         late taps whose activation only exists post-scan).
+- ``tap_weighted_grads``   same, from explicit (a, g) (a ghost-banked book,
+                         or a late tap).
 
 Canonical layouts (stack dims folded into the row dim N):
 - matmul:     a (N, T, D), g (N, T, p); N = prod(stack) * B * G
@@ -62,6 +62,11 @@ KernelChoices = Optional[Mapping[str, str]]
 
 TAP_NORM_SCOPE = "dp.tap_norm"
 
+# Tile sizes of the XLA norm branches: the ghost Gram's position block and
+# the instantiated gradient's fan-in block (the tuner times the same ones).
+GHOST_BLOCK = 512
+INST_BLOCK_D = 8192
+
 
 @contextlib.contextmanager
 def _tap_norm_scope(meta: TapMeta):
@@ -82,8 +87,7 @@ def _check_embedding_vocab(meta: TapMeta, where: str) -> None:
             f"embedding tap {meta.param_path!r} has vocab size {meta.D} >= "
             f"2^24 ({MAX_EXACT_FP32_ID}): {where} carries token ids as "
             "float32, which cannot represent ids that large exactly, so "
-            "high vocab indices would be silently corrupted. Run this model "
-            "on the explicit *_taps engine (ids stay integer) or shard the "
+            "high vocab indices would be silently corrupted. Shard the "
             "embedding below 2^24 rows per tap."
         )
 
@@ -129,47 +133,43 @@ def tap_norm_sq(
     g: jax.Array,
     *,
     mode: str = "mixed_ghost",
-    decision_by: str = "space",
-    ghost_block: int = 512,
-    inst_block_d: int = 8192,
     override: Optional[str] = None,
-    include_bias: bool = True,
     kernels: KernelChoices = None,
 ) -> jax.Array:
     """Per-sample squared norm contributions: (B,) fp32 (weight + bias).
 
     ``override`` forces the matmul branch (tuner ClipPlan); both branches
     compute the same norm, so it changes cost only, never the result.
-    ``include_bias=False`` skips the bias term (book-keeping banks it
-    separately as ``psg_b`` and adds its norm from the bank).  ``kernels``
-    picks the Pallas-vs-XLA impl per dispatch op (also cost-only).
+    ``kernels`` picks the Pallas-vs-XLA impl per dispatch op (also
+    cost-only).
     """
     with _tap_norm_scope(meta):
         return _tap_norm_sq(
-            meta, a, g, mode=mode, decision_by=decision_by, ghost_block=ghost_block,
-            inst_block_d=inst_block_d, override=override, include_bias=include_bias,
-            kernels=kernels,
+            meta, a, g, branch=decide(meta, mode=mode, override=override),
+            include_bias=True, kernels=kernels,
         )
 
 
 def _tap_norm_sq(
-    meta: TapMeta, a: Optional[jax.Array], g: jax.Array, *, mode: str, decision_by: str,
-    ghost_block: int, inst_block_d: int, override: Optional[str], include_bias: bool,
-    kernels: KernelChoices,
+    meta: TapMeta, a: Optional[jax.Array], g: jax.Array, *, branch: str,
+    include_bias: bool, kernels: KernelChoices,
 ) -> jax.Array:
+    """``tap_norm_sq`` on a decided ``branch`` (read by matmul taps only).
+
+    ``include_bias=False`` skips the bias term (book-keeping banks it
+    separately as ``psg_b`` and adds its norm from the bank)."""
     g = g.astype(jnp.float32)
     total = jnp.zeros((meta.batch_size,), jnp.float32)
 
     if meta.kind == "matmul":
-        branch = decide(meta, mode=mode, by=decision_by, override=override)
         aa, gg = _canonical_ag(meta, a, g)
         if branch == "ghost":
             rows = dispatch.ghost_norm_sq(
-                aa, gg, block=ghost_block,
+                aa, gg, block=GHOST_BLOCK,
                 impl=dispatch.kernels_arg(kernels, "ghost_norm"),
             )
         else:
-            rows = gops.instantiated_norm_sq(aa, gg, block_d=inst_block_d)
+            rows = gops.instantiated_norm_sq(aa, gg, block_d=INST_BLOCK_D)
         total = total + _per_sample(meta, rows)
     elif meta.kind == "embedding":
         if jnp.issubdtype(a.dtype, jnp.floating):
@@ -310,9 +310,6 @@ def tap_bank(
     g: jax.Array,
     *,
     mode: str = "mixed_ghost",
-    decision_by: str = "space",
-    ghost_block: int = 512,
-    inst_block_d: int = 8192,
     override: Optional[str] = None,
     kernels: KernelChoices = None,
 ) -> dict[str, jax.Array]:
@@ -331,13 +328,7 @@ def tap_bank(
       the ghost norm (here) and the weighted einsum (later) are formed.
     """
     if mode != "bk_mixed":  # tap_norm_sq scopes its own work
-        return {
-            "n": tap_norm_sq(
-                meta, a, g, mode=mode, decision_by=decision_by,
-                ghost_block=ghost_block, inst_block_d=inst_block_d,
-                override=override, kernels=kernels,
-            )
-        }
+        return {"n": tap_norm_sq(meta, a, g, mode=mode, override=override, kernels=kernels)}
 
     with _tap_norm_scope(meta):
         b = meta.batch_size
@@ -346,7 +337,7 @@ def tap_bank(
         n = jnp.zeros((b,), jnp.float32)
 
         if meta.kind == "matmul":
-            branch = decide(meta, mode="bk_mixed", by=decision_by, override=override)
+            branch = decide(meta, mode="bk_mixed", override=override)
             if branch == "instantiate":
                 psg = _matmul_psg(meta, a, g32)
                 bank["psg"] = psg
@@ -354,9 +345,7 @@ def tap_bank(
             else:
                 bank["a"], bank["g"] = a, g
                 n = n + _tap_norm_sq(
-                    meta, a, g, mode="ghost", decision_by=decision_by,
-                    ghost_block=ghost_block, inst_block_d=inst_block_d,
-                    override=None, include_bias=False, kernels=kernels,
+                    meta, a, g, branch="ghost", include_bias=False, kernels=kernels
                 )
         elif meta.kind == "embedding":
             # a is the fp32-cast ids (taps.Ctx casts before probing): exact for
@@ -365,9 +354,7 @@ def tap_bank(
             _check_embedding_vocab(meta, "the book-keeping bank")
             bank["a"], bank["g"] = a, g
             n = n + _tap_norm_sq(
-                meta, a, g, mode=mode, decision_by=decision_by,
-                ghost_block=ghost_block, inst_block_d=inst_block_d,
-                override=None, include_bias=False, kernels=kernels,
+                meta, a, g, branch="ghost", include_bias=False, kernels=kernels
             )
         else:
             psg = _small_psg(meta, a, g32)

@@ -132,9 +132,6 @@ class ClipRuntime:
     """Static knobs the fused probes need at trace time."""
 
     mode: str = "mixed_ghost"
-    decision_by: str = "space"
-    ghost_block: int = 512
-    inst_block_d: int = 8192
     # measured-cost branch overrides from a tuner ClipPlan, as sorted
     # (tap_name, branch) pairs (tuple: ClipRuntime must stay hashable)
     overrides: tuple[tuple[str, str], ...] = ()
@@ -165,9 +162,9 @@ class Ctx:
       book-keeping mode, the weighted-gradient residuals (core/fused.py).
       Nothing tap-sized ever escapes the backward pass except what the
       algorithm itself must bank.
-    - explicit (``clip`` None): pre-activations get zero taps added and
-      activations recorded; dL/ds comes back as tap cotangents (the
-      ``*_taps`` reference/testing engines and late taps).
+    - explicit (``clip`` None, or a ``late=True`` tap): pre-activations get
+      zero taps added and activations recorded; dL/ds comes back as tap
+      cotangents.
 
     ``taps=None``/``zs=None`` means discovery mode (meta only).
     ``collect=False`` disables DP bookkeeping entirely (serving path).
@@ -257,9 +254,6 @@ class Ctx:
                     ProbeSpec(
                         meta=meta,
                         branch_mode=self.clip.mode,
-                        decision_by=self.clip.decision_by,
-                        ghost_block=self.clip.ghost_block,
-                        inst_block_d=self.clip.inst_block_d,
                         override=self.clip.override_for(full),
                         kernels=self.clip.kernels_for(full),
                     )

@@ -33,16 +33,14 @@ def matmul_fwd_flops(meta: dict[str, TapMeta]) -> float:
     return total
 
 
-def norm_flops(meta: dict[str, TapMeta], mode: str, decision_by: str = "space") -> float:
+def norm_flops(meta: dict[str, TapMeta], mode: str) -> float:
     """Per-sample gradient-norm flops (the clipping module, Table 1)."""
     total = 0.0
     for m in meta.values():
         reps = m.n_stack * max(m.n_groups, 1)
         b = m.batch_size
         if m.kind == "matmul":
-            branch = decide(m, mode=mode if not mode.endswith("_taps") else mode[:-5],
-                            by=decision_by)
-            if branch == "ghost":
+            if decide(m, mode=mode) == "ghost":
                 total += reps * b * (2.0 * m.T * m.T * (m.D + m.p))
             else:
                 total += reps * b * (2.0 * m.T * m.D * m.p)

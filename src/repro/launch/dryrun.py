@@ -35,7 +35,7 @@ import jax.numpy as jnp
 
 from repro.configs.base import ArchConfig, ShapeConfig
 from repro.configs.registry import ARCHS, SHAPES, build_model, get_arch, get_shape
-from repro.core.clipping import discover_meta
+from repro.core.clipping import MODES, discover_meta
 from repro.core.taps import ClipRuntime
 from repro.launch import analysis
 from repro.launch.analytic import cell_flops, extra_fwd_flops, serve_matmul_flops
@@ -277,7 +277,7 @@ def main():
     ap.add_argument("--arch", default=None)
     ap.add_argument("--shape", default=None)
     ap.add_argument("--mesh", default="both", choices=["single", "multi", "both"])
-    ap.add_argument("--mode", default="mixed_ghost")
+    ap.add_argument("--mode", default="mixed_ghost", choices=MODES)
     ap.add_argument("--out", default="results/dryrun")
     ap.add_argument("--no-calibrate", action="store_true")
     ap.add_argument("--no-resume", action="store_true")

@@ -41,6 +41,7 @@ import jax
 import jax.numpy as jnp
 
 from repro.configs.registry import build_model, get_arch
+from repro.core.clipping import MODES
 from repro.core.engine import PrivacyEngine
 from repro.data.pipeline import DataPipeline
 from repro.data.poisson import poisson_sample_mask
@@ -79,9 +80,9 @@ def parse_args(argv=None):
     ap.add_argument("--batch", type=int, default=8)
     ap.add_argument("--seq", type=int, default=128)
     ap.add_argument("--lr", type=float, default=1e-3)
-    ap.add_argument("--mode", default="mixed_ghost",
-                    help="clipping mode (see core.clipping.MODES), or 'auto' "
-                         "to adopt the tuned plan's recommended_mode")
+    ap.add_argument("--mode", default="mixed_ghost", choices=MODES + ("auto",),
+                    help="clipping mode, or 'auto' to adopt the tuned plan's "
+                         "recommended_mode")
     ap.add_argument("--clip-norm", type=float, default=1.0)
     ap.add_argument("--clip-policy", default="fixed",
                     choices=["fixed", "automatic", "quantile", "per_layer"],

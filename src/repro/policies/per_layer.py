@@ -17,10 +17,10 @@ accounting is unchanged — the policy only re-shapes *where* the budget goes.
 
 Cost per executor family (the factors are per (group, sample)):
 
-- book-keeping (``bk_mixed``/``bk_mixed_taps``): free — each tap's bank is
+- book-keeping (``bk_mixed``): free — each tap's bank is
   contracted against its own group's factors, same einsums;
 - vmap oracle: free — per-leaf scaling;
-- second-backward modes: one extra backward *per group* (the pullback
+- second backward (``mixed_ghost``): one extra backward *per group* (the pullback
   cotangent is per-sample, not per-param) — correct everywhere, but prefer
   the book-keeping engine when G is large.
 

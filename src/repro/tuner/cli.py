@@ -31,7 +31,7 @@ import sys
 import jax
 
 from repro.configs.registry import build_model, get_arch
-from repro.core.clipping import ClipConfig, discover_meta, dp_value_and_clipped_grad
+from repro.core.clipping import MODES, ClipConfig, discover_meta, dp_value_and_clipped_grad
 from repro.core.decision import decide
 from repro.data.synthetic import synthetic_arch_batch
 from repro.tuner import max_batch as mb
@@ -68,7 +68,7 @@ def parse_args(argv=None):
     ap.add_argument("--skip-max-batch", action="store_true")
     ap.add_argument("--skip-remeasure", action="store_true",
                     help="do not re-time branches at the tuned physical batch")
-    ap.add_argument("--mode", default="mixed_ghost",
+    ap.add_argument("--mode", default="mixed_ghost", choices=MODES,
                     help="clipping mode the max-batch search compiles")
     ap.add_argument("--consensus", action="store_true",
                     help="fleet agreement after measuring: adopt the "

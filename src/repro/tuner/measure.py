@@ -45,6 +45,7 @@ import jax
 import jax.numpy as jnp
 
 from repro.core.decision import decide
+from repro.core.ghost import GHOST_BLOCK, INST_BLOCK_D
 from repro.core.taps import TapMeta
 from repro.kernels import dispatch
 from repro.kernels.ghost_norm import ops as gops
@@ -73,8 +74,6 @@ class MeasureConfig:
 
     repeats: int = 5  # timed iterations; the median is kept
     warmup: int = 2  # discarded iterations (compile + caches)
-    ghost_block: int = 512
-    inst_block_d: int = 8192
     # clamp the row dim N = stack*B*groups during profiling; timings scale
     # ~linearly in N, so the *comparison* is preserved while huge-batch taps
     # stay cheap to profile (tuning must never OOM the device it is sizing).
@@ -170,7 +169,7 @@ def measure_tap_kernels(
         race(
             "ghost_norm",
             lambda impl: lambda x, y: dispatch.ghost_norm_sq(
-                x, y, block=cfg.ghost_block, impl=impl
+                x, y, block=GHOST_BLOCK, impl=impl
             ),
             a, g,
         )
@@ -230,11 +229,11 @@ def measure_tap(
     # train time, so the shared im2col cost cancels out of THIS comparison)
     ghost_fn = jax.jit(
         lambda x, y: dispatch.ghost_norm_sq(
-            x, y, block=cfg.ghost_block, impl=k_ghost
+            x, y, block=GHOST_BLOCK, impl=k_ghost
         )
     )
     inst_fn = jax.jit(
-        lambda x, y: gops.instantiated_norm_sq(x, y, block_d=cfg.inst_block_d)
+        lambda x, y: gops.instantiated_norm_sq(x, y, block_d=INST_BLOCK_D)
     )
     ghost_us = time_us(ghost_fn, a, g, repeats=cfg.repeats, warmup=cfg.warmup)
     inst_us = time_us(inst_fn, a, g, repeats=cfg.repeats, warmup=cfg.warmup)
@@ -265,7 +264,7 @@ def measure_tap(
             aa = unfold2d(xraw, meta.conv).astype(jnp.float32)
             yy = y.reshape(n, meta.T, meta.p)
             norms = dispatch.ghost_norm_sq(
-                aa, yy, block=cfg.ghost_block, impl=k_ghost
+                aa, yy, block=GHOST_BLOCK, impl=k_ghost
             )
             wg = _book(aa, yy, cc, k_psg)
             return norms, wg
@@ -283,7 +282,7 @@ def measure_tap(
     else:
         def bk_ghost(x, y, cc):
             norms = dispatch.ghost_norm_sq(
-                x, y, block=cfg.ghost_block, impl=k_ghost
+                x, y, block=GHOST_BLOCK, impl=k_ghost
             )
             wg = _book(x.astype(jnp.float32), y, cc, k_psg)
             return norms, wg

@@ -2,9 +2,9 @@
 
 A 2-layer BEiT (d 64, 4 heads, 32 px images in patches of 8: 17 tokens, a
 4x4 window, a 52-row relative position table per layer) with seeded
-weights: each clipping mode the engine offers for its taps gives the
-per-sample norms and the clipped sum of ``vmap(grad)`` at ``highest``
-precision; the table taps always instantiate; the biased flash op matches
+weights: each clipping mode, and ``mixed_ghost`` with every matmul tap
+forced onto one branch, gives the per-sample norms and the clipped sum of
+``vmap(grad)`` at ``highest`` precision; the table taps always instantiate; the biased flash op matches
 softmax(Q K^T s + bias) V and its gradient; the auditor finds every
 parameter behind its tap.
 """
@@ -24,6 +24,8 @@ from repro.core.taps import Ctx
 from repro.kernels.flash_attention.ops import flash_attention
 from repro.models.vit import BEiT
 from repro.nn.attention import relative_position_index
+
+from helpers import forced_plan
 
 SMALL = dataclasses.replace(
     BEIT_LARGE, n_layers=2, d_model=64, n_heads=4, n_kv=4, d_ff=256,
@@ -78,17 +80,22 @@ def test_small_beit_layout():
     assert logits.shape == (BATCH, 10)
 
 
-@pytest.mark.parametrize("mode", ["mixed_ghost", "ghost", "fastgradclip", "bk_mixed"])
-def test_engine_matches_vmap_grad(mode):
-    """``fastgradclip`` is the engine's instantiate-everywhere mode."""
+@pytest.mark.parametrize("mode,force", [
+    ("mixed_ghost", None), ("mixed_ghost", "ghost"), ("mixed_ghost", "instantiate"),
+    ("bk_mixed", None),
+], ids=["mixed_ghost", "ghost", "instantiate", "bk_mixed"])
+def test_engine_matches_vmap_grad(mode, force):
+    """``force`` runs ``mixed_ghost`` under a plan that puts every matmul tap
+    on one branch: the paper's ghost-only and instantiate-only algorithms."""
     model, batch = _model(), _batch()
     params = _params(model)
     norms0, _ = _oracle(model, params, batch, 1.0)
     clip_norm = float(jnp.median(norms0))  # some samples clipped, some not
     ref_norms, ref_sum = _oracle(model, params, batch, clip_norm)
+    plan = forced_plan(model.loss_with_ctx, params, batch, force) if force else None
     engine = PrivacyEngine(
         loss_with_ctx=model.loss_with_ctx, batch_size=BATCH, sample_size=1000, steps=10,
-        max_grad_norm=clip_norm, noise_multiplier=1.0, mode=mode,
+        max_grad_norm=clip_norm, noise_multiplier=1.0, mode=mode, plan=plan,
     )
     with jax.default_matmul_precision("highest"):
         _, got_sum, aux = jax.jit(engine.clipped_grad_fn())(params, batch)
@@ -100,8 +107,9 @@ def test_engine_matches_vmap_grad(mode):
         assert err < 5e-5, (jax.tree_util.keystr(path), err)
 
 
-@pytest.mark.parametrize("mode", ["mixed_ghost", "ghost", "fastgradclip", "bk_mixed"])
-def test_table_taps_instantiate(mode):
+@pytest.mark.parametrize("override", [None, "ghost"])
+@pytest.mark.parametrize("mode", ["mixed_ghost", "bk_mixed"])
+def test_table_taps_instantiate(mode, override):
     model = _model()
     meta = discover_meta(model.loss_with_ctx, _params(model), _batch())
     tables = {n: m for n, m in meta.items() if m.kind == "table"}
@@ -109,8 +117,7 @@ def test_table_taps_instantiate(mode):
     m = tables["layers/attn/rel_pos"]
     assert (m.T, m.D, m.p, m.stack_dims) == (17 * 17, 52, 4, (2,))
     assert m.param_path == "layers/attn/relative_position_bias_table"
-    assert decide(m, mode=mode) == "instantiate"
-    assert decide(m, mode=mode, override="ghost") == "instantiate"
+    assert decide(m, mode=mode, override=override) == "instantiate"
 
 
 def test_table_tap_norm_is_the_table_gradient_norm():

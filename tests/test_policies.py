@@ -4,7 +4,7 @@ accounting, plan/consensus policy gates, and checkpoint round-trips.
 The central claims:
 - every policy (fixed / automatic / quantile / per_layer) produces clipped
   gradients matching a naive per-sample-gradient reference on EVERY executor
-  family (vmap / fused second-backward / explicit taps / book-keeping);
+  family (vmap / fused second-backward / book-keeping);
 - an adversarially flipped-branch tuner plan changes no policy's output
   (branch decisions are policy-independent);
 - the quantile policy's indicator release is billed exactly (manual RDP
@@ -36,7 +36,7 @@ from repro.utils.tree import flatten_dict
 
 from helpers import lm_batch, max_tree_diff
 
-MODES = ["vmap", "mixed_ghost", "mixed_ghost_taps", "bk_mixed", "bk_mixed_taps"]
+MODES = ["vmap", "mixed_ghost", "bk_mixed"]
 
 
 class _MLPModel:

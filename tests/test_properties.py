@@ -150,5 +150,7 @@ def test_decide_forced_branches(t, d, p):
     assert decide(mk("scale")) == "instantiate"
     assert decide(mk("bias")) == "instantiate"
     assert decide(mk("dw_conv")) == "instantiate"
-    assert decide(mk("matmul"), mode="ghost") == "ghost"
-    assert decide(mk("matmul"), mode="fastgradclip") == "instantiate"
+    # one branch everywhere is a plan, not a mode
+    for mode in ("ghost", "fastgradclip"):
+        with pytest.raises(ValueError, match="unknown clipping mode"):
+            decide(mk("matmul"), mode=mode)

@@ -17,6 +17,7 @@ from repro.core.clipping import ClipConfig, discover_meta, dp_value_and_clipped_
 from repro.core.decision import decide, ghost_is_cheaper
 from repro.core.taps import Ctx, TapMeta
 from repro.nn.module import Dense
+from repro.nn.xlstm import SLSTMBlock
 from repro.tuner import (
     ClipPlan,
     MeasureConfig,
@@ -76,11 +77,13 @@ def test_override_never_wins_over_forced_kinds():
 
 
 def test_override_never_wins_over_reference_modes():
-    # the pure modes exist to measure a fixed branch everywhere; a plan must
-    # not silently turn a 'ghost' benchmark into mixed execution
+    # the fixed-branch modes are gone (a plan forcing every tap expresses
+    # them), so no override can ride on their names: decide refuses them
     m = _meta(T=1, D=64, p=64)
-    assert decide(m, mode="ghost", override="instantiate") == "ghost"
-    assert decide(m, mode="fastgradclip", override="ghost") == "instantiate"
+    with pytest.raises(ValueError, match="unknown clipping mode"):
+        decide(m, mode="ghost", override="instantiate")
+    with pytest.raises(ValueError, match="unknown clipping mode"):
+        decide(m, mode="fastgradclip", override="ghost")
 
 
 def test_bk_branch_rule_is_bank_size_driven():
@@ -265,8 +268,29 @@ class TwoLayer:
         return jnp.mean((out - batch["y"]) ** 2, axis=(1, 2))
 
 
-def _two_layer_setup():
-    model = TwoLayer()
+class WithSLSTM(TwoLayer):
+    """TwoLayer around an sLSTM block, whose recurrent weight is a
+    ``late=True`` tap: its norm and book-keeping gradient go through the
+    fused engine's explicit channel (``ghost.tap_norm_sq`` and
+    ``ghost.tap_weighted_grads`` on the recorded activation)."""
+
+    def __init__(self):
+        super().__init__()
+        self.sl = SLSTMBlock("sl", 8, n_heads=2)
+
+    def init(self, key):
+        k1, k2 = jax.random.split(key)
+        return {**super().init(k1), "sl": self.sl.init(k2)}
+
+    def loss_with_ctx(self, params, batch, ctx: Ctx):
+        h = jax.nn.relu(self.f1(params["f1"], batch["x"], ctx.scope("f1")))
+        h, _ = self.sl(params["sl"], h, ctx.scope("sl"))
+        out = self.f2(params["f2"], h, ctx.scope("f2"))
+        return jnp.mean((out - batch["y"]) ** 2, axis=(1, 2))
+
+
+def _two_layer_setup(model_cls=TwoLayer):
+    model = model_cls()
     params = model.init(jax.random.PRNGKey(0))
     k1, k2 = jax.random.split(jax.random.PRNGKey(1))
     batch = {
@@ -276,17 +300,18 @@ def _two_layer_setup():
     return model, params, batch
 
 
-@pytest.mark.parametrize(
-    "mode", ["mixed_ghost", "mixed_ghost_taps", "bk_mixed", "bk_mixed_taps"]
-)
-def test_plan_changes_branch_not_math(mode):
+@pytest.mark.parametrize("mode,model_cls", [
+    ("mixed_ghost", TwoLayer), ("mixed_ghost", WithSLSTM),
+    ("bk_mixed", TwoLayer), ("bk_mixed", WithSLSTM),
+], ids=["mixed_ghost", "mixed_ghost-late", "bk_mixed", "bk_mixed-late"])
+def test_plan_changes_branch_not_math(mode, model_cls):
     """Clipped grads under an adversarially flipped three-way plan == analytic.
 
     Both branch maps are inverted: the norm branch of the second-backward
     modes AND the bank branch of book-keeping.  Either way the math is
     identical — the plan moves cost, never results.
     """
-    model, params, batch = _two_layer_setup()
+    model, params, batch = _two_layer_setup(model_cls)
     metas = discover_meta(model.loss_with_ctx, params, batch)
 
     def flip(branch):
@@ -315,12 +340,14 @@ def test_plan_changes_branch_not_math(mode):
     assert max_tree_diff(g1, g2) < 1e-5
 
 
-@pytest.mark.parametrize("mode", ["mixed_ghost", "bk_mixed", "bk_mixed_taps"])
-def test_plan_kernel_choice_changes_no_output(mode):
+@pytest.mark.parametrize("mode,model_cls", [
+    ("mixed_ghost", TwoLayer), ("bk_mixed", TwoLayer), ("bk_mixed", WithSLSTM),
+], ids=["mixed_ghost", "bk_mixed", "bk_mixed-late"])
+def test_plan_kernel_choice_changes_no_output(mode, model_cls):
     """Flipping the plan-recorded kernel impl (v5 ``kernels``) re-routes the
     hot ops through the other implementation without changing any output —
     the acceptance oracle for the dispatch layer."""
-    model, params, batch = _two_layer_setup()
+    model, params, batch = _two_layer_setup(model_cls)
     metas = discover_meta(model.loss_with_ctx, params, batch)
     from repro.tuner.measure import KERNEL_OPS_BY_KIND
 
